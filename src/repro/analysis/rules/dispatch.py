@@ -23,13 +23,13 @@ import re
 from repro.analysis.engine import Rule
 
 _EXEMPT = re.compile(r"(^|/)(repro/(kernels|api)/|tests/)")
-_EXEC_MODULES = {"ops", "bgemm", "bitserial", "bitpack", "wqmm"}
+_EXEC_MODULES = {"ops", "bitserial", "bitpack", "wqmm"}
 
 
 class DispatchBypass(Rule):
     name = "api-dispatch-bypass"
     description = ("no direct import of the kernel execution modules "
-                   "(repro.kernels.{ops,bgemm,bitserial,bitpack,wqmm}) "
+                   "(repro.kernels.{ops,bitserial,bitpack,wqmm}) "
                    "outside kernels/ and api/ — dispatch through repro.api; "
                    "artifact/oracle modules (kernels.sgt, kernels.ref) are "
                    "exempt")

@@ -2,7 +2,7 @@
 
 QGTC's claim is a BIT-EXACT integer path: bit-plane popcount GEMMs whose
 accumulators, tiles and outputs are int32 end to end.  A float dtype
-sneaking into ``kernels/bitserial.py``/``bgemm.py``/``sgt.py``/``ops.py``
+sneaking into ``kernels/bitserial.py``/``sgt.py``/``ops.py``
 silently breaks exactness (rounding) and, on real hardware, knocks the
 kernel off the integer tensor-core path.  The ONE sanctioned exception is
 the §4.5 fused-requantize epilogue (alpha/beta rescale + clip), which is
@@ -22,7 +22,7 @@ import re
 
 from repro.analysis.engine import Rule
 
-_SCOPE = re.compile(r"(^|/)repro/kernels/(bitserial|bgemm|sgt|ops)\.py$")
+_SCOPE = re.compile(r"(^|/)repro/kernels/(bitserial|sgt|ops)\.py$")
 
 _FLOAT_DTYPES = {"float32", "float64", "float16", "bfloat16", "float_"}
 # elementwise float producers/consumers that have no business in an
@@ -43,7 +43,7 @@ class KernelIntPurity(Rule):
     name = "kernel-int-purity"
     description = ("no float dtypes, float literals, astype(float) or "
                    "float elementwise ops inside the integer kernel "
-                   "modules (kernels/{bitserial,bgemm,sgt,ops}.py); the "
+                   "modules (kernels/{bitserial,sgt,ops}.py); the "
                    "fused §4.5 epilogue is waived explicitly")
 
     def applies_to(self, path: str) -> bool:

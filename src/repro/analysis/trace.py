@@ -44,7 +44,7 @@ __all__ = ["run_trace_checks", "check_backend", "check_policy_sites",
 # path allows exactly these plus elementwise epilogue arithmetic
 _EPILOGUE_OK = {
     # containers (contents are checked recursively)
-    "pjit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
+    "jit", "pjit", "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
     "remat", "checkpoint", "cond", "while", "scan", "pallas_call",
     # data movement (incl. pallas Ref reads/writes of the alpha/beta refs)
     "convert_element_type", "broadcast_in_dim", "reshape", "transpose",

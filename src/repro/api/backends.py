@@ -2,12 +2,13 @@
 
   xla_dot  — per-bit-plane int8 dot products through XLA (MXU emulation);
              portable, fast on any jax backend; registered first so it is
-             the default and the capability-fallback of last resort.
+             the default off a TPU and the capability-fallback of last
+             resort.
   popcount — packed AND+popcount in pure jnp: the paper's bit-serial
              VPU semantics, bit-exact oracle for the kernels.
   pallas   — the TPU Pallas kernels (kernels/ops.py): tiled bit-serial
              GEMM with zero-tile jumping, tile reuse and fused epilogues;
-             runs under interpret mode off-TPU.
+             the default on a TPU; runs under interpret mode off-TPU.
 
 All three produce IDENTICAL int32 results for any (s, t) in 1..8 — that is
 the repo's core exactness invariant, enforced by tests/test_api_dispatch.py.
@@ -154,12 +155,9 @@ class PallasBackend(Backend):
         return kops.bgemm(a_packed, b_packed, policy=policy, tiles=tiles)
 
     def bitpack(self, x, scale, zero, *, nbits, policy):
-        from repro.core import bitops
         from repro.kernels import ops as kops
 
-        out = kops.bitpack(x, scale, zero, nbits=nbits, policy=policy)
-        words = -(-x.shape[1] // bitops.WORD)  # crop block padding words
-        return out[:, :, :words]
+        return kops.bitpack(x, scale, zero, nbits=nbits, policy=policy)
 
     def bitserial_fused(self, a_packed, b_packed, alpha, beta, *,
                         out_bits, relu, policy, tiles=None):

@@ -18,8 +18,9 @@ Fields map onto the paper's knobs:
                             loop inside one kernel so A-tile loads are O(1)
   fused_requantize        — fuse the §4.5 rescale+requantize epilogue into
                             the GEMM when the backend supports it
-  interpret               — Pallas interpret-mode override; None = auto
-                            (interpret everywhere except real TPU)
+  interpret               — Pallas interpret mode; None = auto (interpret
+                            off a TPU, compile on one). True on a TPU
+                            raises: the chip never runs the interpreter
 """
 from __future__ import annotations
 

@@ -11,6 +11,10 @@ One dispatch seam for every quantized GEMM in the repo:
                                           per-call override > active context
                                           > registered-capability fallback
 
+With nothing configured, the default engine follows the device: ``pallas``
+on a TPU (the chip runs the paper's kernels), else the first registered
+backend.
+
 Fallback: if the active backend can't run an op (probed via
 ``Backend.supports``), the first *registered* backend that can is used and a
 RuntimeWarning is emitted once per (backend, op) pair. An *explicitly*
@@ -20,6 +24,8 @@ from __future__ import annotations
 
 import contextvars
 import warnings
+
+import jax
 
 from repro.api.backend import Backend, UnsupportedOpError
 from repro.api.policy import DEFAULT_POLICY, ExecutionPolicy
@@ -106,10 +112,11 @@ def current() -> tuple[Backend, ExecutionPolicy]:
     ctx = _active.get()
     name = (ctx[0] if ctx and ctx[0] is not None else _default[0])
     pol = (ctx[1] if ctx and ctx[1] is not None else _default[1])
-    if name is None:  # no default configured yet: first registered backend
+    if name is None:  # no default configured yet: follow the device
         if not _ORDER:
             raise RuntimeError("no backends registered")
-        name = _ORDER[0]
+        name = ("pallas" if "pallas" in _REGISTRY
+                and jax.default_backend() == "tpu" else _ORDER[0])
     return _REGISTRY[name], pol
 
 

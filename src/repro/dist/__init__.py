@@ -13,10 +13,6 @@ See docs/dist.md for the full rule tables, checkpoint layout, and the
 compressed-collective semantics (QGTC §4.5 bandwidth-optimized transfer;
 Tango-style quantized gradient all-reduce).
 """
-from repro.dist import compat as _compat
-
-_compat.install()  # modern jax.shard_map spelling on older jax
-
 from repro.dist import checkpoint, collectives, elastic, sharding
 from repro.dist.sharding import (constrain, current_ctx, make_rules,
                                  named_sharding, shard_ctx)

@@ -1,13 +1,18 @@
 """Pallas TPU kernel: quantize (Eq. 2) + 3D-stacked bit compression (§4.2).
 
-    x (M, K) f32  ->  packed (nbits, M, ceil(K/32)) uint32
+    x (M, K) f32  ->  packed (nbits, M, K/32) uint32
 
-Packing is a shift-and-or tree on the VPU: the K axis is viewed as
-(words, 32) and each bit lane is shifted into place and summed in uint32.
-(We considered packing via an int matmul against a block-diagonal
-power-of-two matrix — MXU-friendly — but fp32/int MXU accumulation cannot
-represent 2^31 sums exactly, so the VPU tree is the correct TPU lowering;
-recorded as a changed assumption in DESIGN.md.)
+Packing is a shift-and-sum tree on the VPU: each 32-column word of K
+has its bit lanes shifted into place, and the disjoint bits
+are summed in int32 (a sum with no carries is their OR; Mosaic has no
+unsigned reductions and no float32 -> uint32 cast, so the integer work
+stays in int32 until the final same-width conversion). We considered
+packing via an int matmul against a block-diagonal power-of-two matrix —
+MXU-friendly — but fp32/int MXU accumulation cannot represent 2^31 sums
+exactly, so the VPU tree is the correct TPU lowering.
+
+A block spans whole rows of x: the packed word axis is the output's lane
+axis, and only its full extent is a legal block there for any K.
 
 The kernel fuses quantization so full-precision activations stream HBM->VMEM
 once and only packed words stream back (the §4.5 fusion contract).
@@ -21,23 +26,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_BLOCK_M = 128
-DEFAULT_BLOCK_W = 8  # words per block => 256 K-elements
 
 
 def _kernel(x_ref, scale_ref, zero_ref, o_ref, *, nbits, k_true):
-    x = x_ref[...]  # (BM, BW*32) f32
+    x = x_ref[...]  # (BM, K) f32
     bm, k = x.shape
     q = jnp.clip(jnp.floor((x - zero_ref[0, 0]) / scale_ref[0, 0]),
-                 0.0, float((1 << nbits) - 1)).astype(jnp.uint32)
+                 0.0, float((1 << nbits) - 1)).astype(jnp.int32)
     # Zero the K-padding region: padded input columns would otherwise
     # quantize to floor(-zero/scale) != 0 and corrupt the packed planes.
-    col = pl.program_id(1) * k + jax.lax.broadcasted_iota(jnp.int32, (bm, k), 1)
-    q = jnp.where(col < k_true, q, jnp.uint32(0))
-    qw = q.reshape(bm, k // 32, 32)
-    weights = jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32)
-    for i in range(nbits):
-        plane = (qw >> jnp.uint32(i)) & jnp.uint32(1)
-        o_ref[i] = jnp.sum(plane * weights[None, None, :], axis=-1, dtype=jnp.uint32)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bm, k), 1)
+    q = jnp.where(col < k_true, q, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bm, 32), 1)
+    for w in range(k // 32):  # static word slices: Mosaic cannot split lanes
+        qw = q[:, 32 * w:32 * (w + 1)]
+        for i in range(nbits):
+            plane = ((qw >> i) & 1) << lane
+            o_ref[i, :, w:w + 1] = jnp.sum(
+                plane, axis=1, keepdims=True).astype(jnp.uint32)
 
 
 def bitpack(
@@ -48,27 +54,25 @@ def bitpack(
     k_true: int | None = None,
     *,
     block_m: int = DEFAULT_BLOCK_M,
-    block_w: int = DEFAULT_BLOCK_W,
     interpret: bool = False,
 ) -> jax.Array:
-    """x must be pre-padded: M % block_m == 0, K % (block_w*32) == 0."""
+    """x must be pre-padded: M % block_m == 0, K % 32 == 0."""
     m, k = x.shape
-    assert m % block_m == 0 and k % (block_w * 32) == 0, (m, k)
+    assert m % block_m == 0 and k % 32 == 0, (m, k)
     if k_true is None:
         k_true = k
     w = k // 32
-    mt, wt = m // block_m, w // block_w
     scale = jnp.asarray(scale, jnp.float32).reshape(1, 1)
     zero = jnp.asarray(zero, jnp.float32).reshape(1, 1)
     return pl.pallas_call(
         functools.partial(_kernel, nbits=nbits, k_true=k_true),
-        grid=(mt, wt),
+        grid=(m // block_m,),
         in_specs=[
-            pl.BlockSpec((block_m, block_w * 32), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((block_m, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((nbits, block_m, block_w), lambda i, j: (0, i, j)),
+        out_specs=pl.BlockSpec((nbits, block_m, w), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbits, m, w), jnp.uint32),
         interpret=interpret,
     )(x, scale, zero)

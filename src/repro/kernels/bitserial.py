@@ -2,14 +2,45 @@
 
     A_packed (s, M, W) uint32  x  B_packed (t, W, N) uint32  ->  C (M, N) int32
     C = sum_{i<s, j<t} 2^(i+j) * popcount_gemm(A_i, B_j)
+    popcount_gemm(A, B)[m, n] = sum_w popcount(A[m, w] & B[w, n])
+
+The 1-bit GEMM (paper Eq. 7, the b1-WMMA analogue) is the s = t = 1 case;
+``kernels.ops.bgemm`` calls this kernel with one plane per operand.
+
+Tile layout. A TPU block's last two dimensions must be multiples of
+(8, 128) or the array's full extent. The paper's k-tile is ``block_w``
+packed words (4 words = the 8x128 adjacency tile), which is neither on
+A's word (lane) axis. So both operands are re-blocked with the k-tile
+index as a leading dimension:
+
+    A (s, M, W) -> (s, W/bw, M, bw)     block (s, ., block_m, bw)
+    B (t, W, N) -> (t, W/bw, bw, N)     block (t, ., bw, block_n)
+
+Inside a block ``bw`` is the full minor extent, so every tile width is
+legal and each grid step still sees a (block_m, bw) tile of A and a
+(bw, block_n) tile of B. Sparse-graph translation is the bw = 1 case.
+
+Known cost of this layout, not yet timed: the re-blocking of A is an XLA
+transpose in HBM on every call, and the re-blocked A keeps the chip's
+(8, 128) tiled layout with ``bw`` as its minor dim, so each (block_m, bw)
+tile is padded to 128 lanes. A's stored and DMA'd bytes are therefore
+128/bw times its packed footprint: 32x at the default bw = 4, 128x for
+SGT's bw = 1 (the compiled v5e layout is ``u32[s,W/bw,M,bw]`` with tile
+T(8,128)). B keeps N as its lane axis and is not padded.
+
+Compute modes (TPU adaptation of the 1-bit Tensor Core):
+  'vpu' — bit-serial: one (BM, BN) popcount(AND) VPU op per packed word,
+          statically unrolled over the tile's words (Mosaic lowers static
+          slices, not ``dynamic_slice``).
+  'mxu' — unpack bit-planes to int8 inside VMEM and issue one int8 MXU dot
+          per tile (32x on-chip expansion on top of the layout cost above).
 
 Non-zero tile reuse (§4.4 "cross-tile reduction") is structural here: for a
 given (m, k) grid step the A tile words are DMA'd into VMEM once and the
 loop over the s*t bit-plane pairs happens *inside* the kernel body, so tile
 loads are O(1) in the bitwidth instead of O(s*t).
 
-Zero-tile jumping (paper §4.3) applies to the multi-bit kernels exactly as
-it does to 1-bit ``bgemm``: occupancy is computed on the OR of A's bit
+Zero-tile jumping (paper §4.3): occupancy is computed on the OR of A's bit
 planes (for GNN aggregation A is the 1-bit adjacency), so a skipped tile is
 zero in every plane and contributes nothing for any bitwidth.
 
@@ -22,8 +53,7 @@ zero in every plane and contributes nothing for any bitwidth.
             same prefetched-remap machinery at single-WORD column
             granularity — the K grid visits only the non-zero word columns
             of each row window, so a tile with one nonzero word costs one
-            step instead of block_w. Strictly stronger than compact at
-            scattered high sparsity.
+            step instead of block_w.
 
 All variants accumulate into a VMEM scratch buffer and write the output
 block once on the last K step — the int32 accumulator never round-trips
@@ -44,11 +74,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.kernels.bgemm import _tile_product
-
 DEFAULT_BLOCK_M = 128
 DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_W = 32
+
+
+def _tile_product(a, b, mode: str):
+    """(BM, BW) uint32 x (BW, BN) uint32 -> (BM, BN) int32 popcount GEMM."""
+    bm, bw = a.shape
+    bn = b.shape[1]
+    if mode == "vpu":
+        acc = jnp.zeros((bm, bn), jnp.int32)
+        for w in range(bw):
+            acc = acc + jax.lax.population_count(
+                a[:, w:w + 1] & b[w:w + 1, :]).astype(jnp.int32)
+        return acc
+    if mode == "mxu":
+        shifts = jnp.arange(32, dtype=jnp.uint32)
+        a_bits = ((a[:, :, None] >> shifts[None, None, :]) & 1).astype(jnp.int8)
+        a_bits = a_bits.reshape(bm, bw * 32)
+        b_bits = ((b[:, None, :] >> shifts[None, :, None]) & 1).astype(jnp.int8)
+        b_bits = b_bits.reshape(bw * 32, bn)
+        return jax.lax.dot_general(
+            a_bits, b_bits, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        )
+    raise ValueError(f"unknown mode {mode!r}")
 
 
 def _plane_accumulate(a_ref, b_ref, mode):
@@ -156,90 +206,78 @@ def _pallas_bitserial(a_packed, b_packed, alpha, beta, *, block_m, block_n,
     fused = alpha is not None
     if fused:
         assert alpha.shape == (m, 1) and beta.shape == (1, n)
-    operands = ([a_packed, b_packed, alpha, beta] if fused
-                else [a_packed, b_packed])
     out_shape = jax.ShapeDtypeStruct((m, n), jnp.int32)
     scratch = [pltpu.VMEM((block_m, block_n), jnp.int32)]
     epi = dict(out_bits=out_bits, relu=relu)
+    o_spec = pl.BlockSpec((block_m, block_n), lambda i, j, k, *pre: (i, j))
 
-    def specs(index_map, kw=block_w):
+    def operands(kw):
+        # the k-tile index leads, so each block's minor dims are (block_m,
+        # kw) / (kw, block_n) with kw the full extent (see module doc)
+        a4 = a_packed.reshape(s, m, w // kw, kw).transpose(0, 2, 1, 3)
+        b4 = b_packed.reshape(t, w // kw, kw, n)
+        return [a4, b4, alpha, beta] if fused else [a4, b4]
+
+    def specs(index_map, kw):
         sp = [
-            pl.BlockSpec((s, block_m, kw),
-                         lambda i, j, k, *pre: (0, i, index_map(i, k, *pre))),
-            pl.BlockSpec((t, kw, block_n),
-                         lambda i, j, k, *pre: (0, index_map(i, k, *pre), j)),
+            pl.BlockSpec((s, None, block_m, kw),
+                         lambda i, j, k, *pre: (0, index_map(i, k, *pre), i, 0)),
+            pl.BlockSpec((t, None, kw, block_n),
+                         lambda i, j, k, *pre: (0, index_map(i, k, *pre), 0, j)),
         ]
         if fused:
             sp += [pl.BlockSpec((block_m, 1), lambda i, j, k, *pre: (i, 0)),
                    pl.BlockSpec((1, block_n), lambda i, j, k, *pre: (0, j))]
         return sp
 
-    o_spec = pl.BlockSpec((block_m, block_n), lambda i, j, k, *pre: (i, j))
-
-    if sgt is not None:
-        # sparse-graph translation: same compact-jump schedule (init at
-        # s==0, compute under s < count, write at s==s_w-1) but the remap
-        # addresses single WORD columns — with a 1-word K block the block
-        # index IS the word id, so the condensed columns are the only
-        # slices of A and B ever DMA'd.
-        idx, cnt, s_w = sgt
-        s_w = max(int(s_w), 1)  # all-zero A: one guarded (no-op) step
-        assert s_w <= w, (s_w, w)
-        assert idx.shape[0] == mt and idx.shape[1] >= s_w and \
-            cnt.shape == (mt,), (idx.shape, cnt.shape, mt, s_w)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(mt, nt, s_w),
-            in_specs=specs(lambda i, k, idx_r, cnt_r: idx_r[i, k], kw=1),
-            out_specs=o_spec,
-            scratch_shapes=scratch,
-        )
-        kern = functools.partial(_kernel_compact, mode=mode, s_max=s_w,
-                                 **epi)
-        return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                              interpret=interpret)(idx, cnt, *operands)
-
-    if compact is not None:
-        idx, cnt, s_max = compact
+    remap = sgt if sgt is not None else compact
+    if remap is not None:
+        # compact and sgt share one schedule (init at s==0, compute under
+        # s < count, write at s==s_max-1); sgt remaps single WORD columns
+        # (kw = 1, so the block index IS the word id), compact remaps
+        # block_w-word k-tiles
+        kw, bound = (1, w) if sgt is not None else (block_w, kt)
+        idx, cnt, s_max = remap
         s_max = max(int(s_max), 1)  # all-zero A: one guarded (no-op) step
-        assert s_max <= kt, (s_max, kt)
+        assert s_max <= bound, (s_max, bound)
         assert idx.shape[0] == mt and idx.shape[1] >= s_max and \
             cnt.shape == (mt,), (idx.shape, cnt.shape, mt, s_max)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(mt, nt, s_max),
-            in_specs=specs(lambda i, k, idx_r, cnt_r: idx_r[i, k]),
+            in_specs=specs(lambda i, k, idx_r, cnt_r: idx_r[i, k], kw),
             out_specs=o_spec,
             scratch_shapes=scratch,
         )
         kern = functools.partial(_kernel_compact, mode=mode, s_max=s_max,
                                  **epi)
         return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                              interpret=interpret)(idx, cnt, *operands)
+                              interpret=interpret)(idx, cnt, *operands(kw))
 
     if occupancy is not None:
         assert occupancy.shape == (mt, kt), (occupancy.shape, mt, kt)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(mt, nt, kt),
-            in_specs=specs(lambda i, k, occ_r: k),
+            in_specs=specs(lambda i, k, occ_r: k, block_w),
             out_specs=o_spec,
             scratch_shapes=scratch,
         )
         kern = functools.partial(_kernel_mask, mode=mode, kt=kt, **epi)
         return pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shape,
-                              interpret=interpret)(occupancy, *operands)
+                              interpret=interpret)(occupancy,
+                                                   *operands(block_w))
 
     kern = functools.partial(_kernel, mode=mode, kt=kt, **epi)
     return pl.pallas_call(
         kern,
         grid=(mt, nt, kt),
-        in_specs=specs(lambda i, k: k),
+        in_specs=specs(lambda i, k: k, block_w),
         out_specs=o_spec,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
-    )(*operands)
+    )(*operands(block_w))
 
 
 def bitserial_gemm(

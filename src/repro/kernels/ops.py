@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels: padding, jump-mode
 plumbing, output cropping, and CPU-interpret dispatch.
 
-On CPU backends the kernels execute under interpret=True (Python semantics,
-exact); on TPU they compile to Mosaic. All wrappers are shape-polymorphic
-over inputs but keep block sizes static.
+On CPU backends the kernels execute under interpret=True (exact, slow);
+on TPU they always compile to Mosaic. All wrappers are shape-polymorphic
+over inputs but keep block sizes static. ``bgemm`` is the one-plane case
+of the bit-serial kernel.
 
 Tunables come from an ``repro.api.ExecutionPolicy`` (``policy=``); explicit
 keyword overrides (``block_m=``, ``jump=``, ...) win over the policy, which
@@ -20,7 +21,6 @@ import jax.numpy as jnp
 
 from repro.api.policy import DEFAULT_POLICY, ExecutionPolicy
 from repro.core import bitops, zerotile
-from repro.kernels import bgemm as _bgemm
 from repro.kernels import bitpack as _bitpack
 from repro.kernels import bitserial as _bitserial
 from repro.kernels import sgt as _sgt
@@ -35,12 +35,22 @@ def auto_interpret() -> bool:
 
 
 def _resolve(policy: ExecutionPolicy | None, **overrides):
-    """Merge explicit kwargs over the policy over DEFAULT_POLICY."""
+    """Merge explicit kwargs over the policy over DEFAULT_POLICY.
+
+    ``interpret`` None resolves to the Pallas interpreter everywhere but
+    on a TPU. On a TPU the kernels always compile: asking for the
+    interpreter there is an error, never a silent slow path.
+    """
     pol = policy if policy is not None else DEFAULT_POLICY
     out = {k: (v if v is not None else getattr(pol, k))
            for k, v in overrides.items()}
-    if "interpret" in out and out["interpret"] is None:
-        out["interpret"] = auto_interpret()
+    if "interpret" in out:
+        if out["interpret"] is None:
+            out["interpret"] = auto_interpret()
+        elif out["interpret"] and not auto_interpret():
+            raise ValueError(
+                "interpret=True on a TPU: the Pallas kernels compile for the "
+                "chip here; leave interpret=None")
     return out
 
 
@@ -73,47 +83,6 @@ def _unpack_tiles(tiles):
     return idx, cnt, s_max, kind
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_w",
-                                             "mode", "jump", "s_max",
-                                             "tiles_kind", "interpret"))
-def _bgemm_call(a_packed, b_packed, tiles_idx, tiles_cnt, occupancy, *,
-                block_m, block_n, block_w, mode, jump, s_max, tiles_kind,
-                interpret):
-    m, _ = a_packed.shape
-    _, n = b_packed.shape
-    a = _pad2(a_packed, block_m, block_w)
-    b = _pad2(b_packed, block_w, block_n)
-    kwargs = dict(block_m=block_m, block_n=block_n, block_w=block_w,
-                  mode=mode, interpret=interpret)
-    if tiles_idx is not None:
-        # precomputed artifacts: no per-call occupancy work at all
-        if tiles_kind == "sgt":
-            out = _bgemm.bgemm(a, b, sgt=(tiles_idx, tiles_cnt, s_max),
-                               **kwargs)
-        else:
-            out = _bgemm.bgemm(a, b, compact=(tiles_idx, tiles_cnt, s_max),
-                               **kwargs)
-    elif jump == "sgt":
-        wocc = _sgt.word_occupancy(a, block_m)
-        idx, cnt = zerotile.compact_tiles(wocc)
-        out = _bgemm.bgemm(a, b, sgt=(idx, cnt, wocc.shape[1]), **kwargs)
-    elif jump == "compact":
-        # a precomputed occupancy map short-circuits the in-call
-        # OR-reduction (precedence: tiles > occupancy > recompute)
-        occ = (occupancy if occupancy is not None
-               else zerotile.tile_occupancy(a, block_m, block_w))
-        idx, cnt = zerotile.compact_tiles(occ)
-        out = _bgemm.bgemm(a, b, compact=(idx, cnt, occ.shape[1]), **kwargs)
-    elif occupancy is not None:
-        out = _bgemm.bgemm(a, b, occupancy=occupancy, **kwargs)
-    elif jump == "mask":
-        occ = zerotile.tile_occupancy(a, block_m, block_w)
-        out = _bgemm.bgemm(a, b, occupancy=occ, **kwargs)
-    else:
-        out = _bgemm.bgemm(a, b, **kwargs)
-    return out[:m, :n]
-
-
 def bgemm(
     a_packed: jax.Array,
     b_packed: jax.Array,
@@ -137,11 +106,10 @@ def bgemm(
     in-call reduction). ``tiles`` may be the tagged 4-tuple from
     ``sgt.sgt_artifacts`` to select the sparse-graph-translation kernel.
     """
-    kw = _resolve(policy, block_m=block_m, block_n=block_n, block_w=block_w,
-                  mode=mode, jump=jump, interpret=interpret)
-    t_idx, t_cnt, s_max, kind = _unpack_tiles(tiles)
-    return _bgemm_call(a_packed, b_packed, t_idx, t_cnt, occupancy,
-                       s_max=s_max, tiles_kind=kind, **kw)
+    return bitserial_gemm(a_packed[None], b_packed[None], policy=policy,
+                          block_m=block_m, block_n=block_n, block_w=block_w,
+                          mode=mode, jump=jump, tiles=tiles,
+                          occupancy=occupancy, interpret=interpret)
 
 
 def _bitserial_jump_artifacts(a, tiles_idx, tiles_cnt, occupancy, jump,
@@ -285,13 +253,13 @@ def bitserial_fused(
                                  **kw)
 
 
-@functools.partial(jax.jit, static_argnames=("nbits", "block_m", "block_w",
+@functools.partial(jax.jit, static_argnames=("nbits", "block_m",
                                              "interpret"))
-def _bitpack_call(x, scale, zero, *, nbits, block_m, block_w, interpret):
+def _bitpack_call(x, scale, zero, *, nbits, block_m, interpret):
     m, k = x.shape
-    xp = _pad2(x, block_m, block_w * 32)
+    xp = _pad2(x, block_m, 32)
     out = _bitpack.bitpack(xp, scale, zero, nbits, k_true=k, block_m=block_m,
-                           block_w=block_w, interpret=interpret)
+                           interpret=interpret)
     return out[:, :m, :]
 
 
@@ -303,16 +271,14 @@ def bitpack(
     nbits: int,
     policy: ExecutionPolicy | None = None,
     block_m: int | None = None,
-    block_w: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Quantize + pack (M,K) f32 -> (nbits, M_pad, ceil(K/32)) uint32.
+    """Quantize + pack (M,K) f32 -> (nbits, M, ceil(K/32)) uint32.
 
-    Output keeps the padded M (callers crop); the word axis reflects K
-    padded to the block boundary (zero words — harmless for GEMM).
+    A kernel block spans whole rows, so only ``block_m`` of the policy
+    applies.
     """
-    kw = _resolve(policy, block_m=block_m, block_w=block_w,
-                  interpret=interpret)
+    kw = _resolve(policy, block_m=block_m, interpret=interpret)
     return _bitpack_call(x, scale, zero, nbits=nbits, **kw)
 
 

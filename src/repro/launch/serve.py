@@ -37,6 +37,7 @@ from repro import configs
 from repro.api import nn as qnn
 from repro.configs.base import smoke_config
 from repro.dist import sharding as shd
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm
 from repro.perf import report
@@ -238,6 +239,7 @@ def main(argv=None) -> dict:
     if args.wq_bits and not 1 <= args.wq_bits <= 8:
         ap.error(f"--wq-bits must be in 1..8 (or 0 to disable), "
                  f"got {args.wq_bits}")
+    init_compile_cache()
     if args.gnn:
         return serve_gnn(args)
 

@@ -25,6 +25,7 @@ import inspect
 import json
 import pathlib
 
+from repro.launch.compile_cache import init_compile_cache
 from repro.tune.sweep import SMOKE_CONFIG, run_sweep
 from repro.tune.table import provenance
 
@@ -64,6 +65,7 @@ def main(argv=None) -> dict:
     args = ap.parse_args(argv)
     if args.smoke == bool(args.config):
         ap.error("pass exactly one of --smoke or --config")
+    init_compile_cache()
     if args.smoke:
         config = dict(SMOKE_CONFIG)
         # candidate rejections point at the literal grid, file:name

@@ -40,6 +40,7 @@ from repro.dist import checkpoint as ckpt
 from repro.dist import sharding as shd
 from repro.dist.elastic import StragglerWatchdog
 from repro.launch import steps as step_lib
+from repro.launch.compile_cache import init_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models import lm
 from repro.train import data as data_lib
@@ -115,7 +116,7 @@ def _train_gnn(cfg, args) -> dict:
             params, ostate, cstate, loss, acc = trainer._train_step_int(
                 params, ostate, cstate, dbatch, sr_key, jnp.uint32(step),
                 cfg, ocfg, tcfg.grad_bits, tcfg.stochastic,
-                tcfg.grad_compress_bits, None)
+                tcfg.grad_compress_bits, tcfg.backend)
         else:
             dbatch = trainer.make_device_batch(batch)
             params, ostate, loss, acc = trainer._train_step(
@@ -179,6 +180,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--stochastic", action="store_true",
                     help="GNN int path: stochastic rounding")
     args = ap.parse_args(argv)
+    init_compile_cache()
 
     cfg = configs.get(args.arch)
     from repro.models import gnn

@@ -130,6 +130,9 @@ class ServeStats:
     # fingerprints re-homed; the new owner re-warms on its first miss)
     cache_rehomed_entries: int = 0
     cache_rehomed_bytes: int = 0
+    # batches executed per backing device ("default" = no mesh): shows
+    # which devices a replica fleet actually ran on
+    device_batches: dict = dataclasses.field(default_factory=dict)
     # per-batch compute latency (timer stopped AFTER device sync),
     # per-request queue->result latency, and per-request queue-wait
     # (submit -> coalesce); all three share the same bounded rolling
@@ -185,6 +188,7 @@ class ServeStats:
             "retry_after_s": round(self.retry_after_s, 6),
             "cache_rehomed_entries": self.cache_rehomed_entries,
             "cache_rehomed_bytes": self.cache_rehomed_bytes,
+            "device_batches": dict(self.device_batches),
         }
         out.update(report.latency_summary(self.batch_latencies_s, "batch_"))
         out.update(report.latency_summary(self.request_latencies_s, "req_"))
@@ -843,12 +847,27 @@ class GNNServer:
         return self._forward(device, entry, packed, meta), entry
 
     def _forward(self, device, entry: TileEntry, packed, meta):
+        key = str(device) if device is not None else "default"
+        self.stats.device_batches[key] = \
+            self.stats.device_batches.get(key, 0) + 1
+        return self._fwd(*self._forward_args(device, entry, packed, meta))
+
+    def _forward_args(self, device, entry: TileEntry, packed, meta) -> tuple:
         pol = self._policy_for_n(entry.adj.shape[0])
         t_idx, t_cnt, s_max, t_kind = self._jump_tiles(entry, pol)
-        return self._fwd(self._params_for(device), entry.adj, packed,
-                         jnp.float32(meta["scale"]),
-                         jnp.float32(meta["zero"]), entry.inv_deg,
-                         t_idx, t_cnt, s_max, t_kind, pol)
+        return (self._params_for(device), entry.adj, packed,
+                jnp.float32(meta["scale"]), jnp.float32(meta["zero"]),
+                entry.inv_deg, t_idx, t_cnt, s_max, t_kind, pol)
+
+    def lowered(self, batch: SubgraphBatch):
+        """The jitted forward lowered, not run, for one batch under this
+        server's backend and policy: ``.as_text()`` is what the device
+        compiles for that batch's shape (e.g. whether a Pallas kernel is
+        in it). Touches neither the cache nor the stats."""
+        self._check_feat_dim(batch)
+        adj, packed, meta = transfer_packed(batch, nbits=self.feat_bits)
+        return self._fwd.lower(*self._forward_args(
+            None, self._build_entry(adj), packed, meta))
 
     def _check_feat_dim(self, batch: SubgraphBatch) -> None:
         if batch.features.shape[1] != self.cfg.in_dim:

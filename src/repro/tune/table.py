@@ -244,6 +244,15 @@ class TuningTable:
 
     def policy_for(self, op: str, *, bits=None, sparsity=None,
                    shape=None) -> ExecutionPolicy | None:
+        """The nearest cell's policy; None (no opinion) for an unknown op
+        or when the table was swept on another JAX backend than this
+        process runs: a CPU-timed winner says nothing about a TPU."""
+        swept_on = self.meta.get("jax_backend")
+        if swept_on is not None:
+            import jax
+
+            if swept_on != jax.default_backend():
+                return None
         e = self.lookup(op, bits=bits, sparsity=sparsity, shape=shape)
         return e.policy if e is not None else None
 

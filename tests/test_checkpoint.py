@@ -77,14 +77,16 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.dist import checkpoint as ckpt
 
 t = {{"w": jnp.arange(64.0).reshape(8, 8), "b": jnp.arange(8.0)}}
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sh = {{"w": NamedSharding(mesh, P("data", "model")),
       "b": NamedSharding(mesh, P("model"))}}
 t_sharded = jax.device_put(t, sh)
 ckpt.save({str(tmp_path)!r}, 5, t_sharded, mesh_shape=mesh.shape)
 
 for shape in [(2, 4), (8, 1), (1, 1)]:
-    mesh2 = jax.make_mesh(shape, ("data", "model"))
+    mesh2 = jax.make_mesh(shape, ("data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     sh2 = {{"w": NamedSharding(mesh2, P("data", "model")),
            "b": NamedSharding(mesh2, P("model"))}}
     restored, m = ckpt.restore({str(tmp_path)!r}, t, shardings=sh2)

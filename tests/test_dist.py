@@ -45,7 +45,8 @@ params, axes = lm.init_lm(jax.random.PRNGKey(0), cfg)
 # single-device reference
 loss_ref, _ = lm.lm_loss(params, batch, cfg)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = shd.make_rules("train")
 with mesh, shd.shard_ctx(mesh, rules):
     p_sh = step_lib.param_shardings(mesh, rules, axes, params)
@@ -75,7 +76,8 @@ params, axes = lm.init_lm(jax.random.PRNGKey(0), cfg)
 batch = data_lib.batch_for_arch(cfg, 0, 0, 8, 32)
 loss_ref, _ = lm.lm_loss(params, batch, cfg)   # fallback path (no mesh)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = shd.make_rules("train")
 with mesh, shd.shard_ctx(mesh, rules):
     p_sh = step_lib.param_shardings(mesh, rules, axes, params)
@@ -104,7 +106,8 @@ from repro.models import lm
 from repro.train import data as data_lib, optimizer as opt
 
 cfg = smoke_config(configs.get("rwkv6-1.6b"))
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 rules = shd.make_rules("train", multi_pod=True)
 with mesh, shd.shard_ctx(mesh, rules):
     params, axes = lm.init_lm(jax.random.PRNGKey(0), cfg)
@@ -148,7 +151,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.dist.collectives import compressed_psum_mean
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 1)
 x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 7.0
 
 def f(x_blk):
@@ -180,7 +184,8 @@ from repro.train import data as data_lib, optimizer as opt
 cfg = smoke_config(configs.get("minitron-8b"))
 batch = data_lib.batch_for_arch(cfg, 0, 0, 8, 32)
 params, axes = lm.init_lm(jax.random.PRNGKey(0), cfg)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 losses = {}
 for name, z3, nm in [("tp", False, 1), ("zero3", True, 1), ("zero3mb2", True, 2)]:
@@ -224,7 +229,8 @@ qph = calibrate(h, S)
 hq = quantize(h, qph)
 inv_deg = 1.0 / (jnp.sum(adj, axis=1, keepdims=True).astype(jnp.float32) + 1)
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = jax.make_mesh((8, 1), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rules = shd.make_rules("train")
 for backend in ("popcount", "pallas"):
     with api.use(backend):
