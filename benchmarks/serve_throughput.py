@@ -385,8 +385,8 @@ def shuffled_arm(scale: float = 0.006, parts_k: int = 8, rounds: int = 3,
         "jump": "none", "median_ms": round(warm.stats.p50_s * 1e3, 3),
         "nodes_per_s": round(nps, 1),
         "cache_hit_rate": round(hit_rate, 4),
-        "full_hit_batches": warm.cache.full_hits,
-        "partial_hit_batches": warm.cache.partial_hits,
+        "full_hit_batches": warm.stats.cache_hits,
+        "partial_hit_batches": warm.stats.cache_partial_hits,
     }
     emit(f"serve_{name}_shuffled", rec["cache_hit_rate"], "hit_rate",
          p50_ms=rec["median_ms"], full_hits=rec["full_hit_batches"],

@@ -23,6 +23,7 @@ import numpy as np
 from repro.core.bitops import np_pack_words
 from repro.graph.batching import SubgraphBatch
 from repro.graph.sparse import sparse_to_dense
+from repro.perf import spans
 
 __all__ = ["pack_compound", "unpack_compound", "pack_feats", "unpack_feats",
            "transfer_dense", "transfer_sparse", "transfer_packed",
@@ -60,12 +61,14 @@ def pack_compound(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dic
     the transfer cost scales with nbits (the paper's bit-level saving
     extends to the link, not just HBM).
     """
-    header, packed, meta = _pack_body(batch, nbits, batch.edges.shape[1])
-    buf = np.concatenate([
-        header,
-        batch.edges.astype(np.int32).view(np.uint32).ravel(),
-        packed.ravel(),
-    ])
+    with spans.span("pack"):
+        header, packed, meta = _pack_body(batch, nbits,
+                                          batch.edges.shape[1])
+        buf = np.concatenate([
+            header,
+            batch.edges.astype(np.int32).view(np.uint32).ravel(),
+            packed.ravel(),
+        ])
     return buf, meta
 
 
@@ -78,8 +81,9 @@ def pack_feats(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dict]:
     Same header/bit-plane layout as :func:`pack_compound`, minus the edges
     (header e_cap = 0).
     """
-    header, packed, meta = _pack_body(batch, nbits, e_cap=0)
-    buf = np.concatenate([header, packed.ravel()])
+    with spans.span("pack"):
+        header, packed, meta = _pack_body(batch, nbits, e_cap=0)
+        buf = np.concatenate([header, packed.ravel()])
     return buf, meta
 
 
@@ -124,19 +128,21 @@ def transfer_sparse(batch: SubgraphBatch, device=None):
 def transfer_packed(batch: SubgraphBatch, nbits: int = 8, device=None):
     """Strategy III (QGTC): one compound transfer + device unpack."""
     buf, meta = pack_compound(batch, nbits)
-    dbuf = jax.device_put(buf, device)
-    adj, packed = unpack_compound(dbuf, n=meta["n"], d=meta["d"],
-                                  nbits=meta["nbits"], e_cap=meta["e_cap"],
-                                  wpf=meta["wpf"])
+    with spans.span("transfer"):
+        dbuf = jax.device_put(buf, device)
+        adj, packed = unpack_compound(dbuf, n=meta["n"], d=meta["d"],
+                                      nbits=meta["nbits"],
+                                      e_cap=meta["e_cap"], wpf=meta["wpf"])
     return adj, packed, meta
 
 
 def transfer_packed_feats(batch: SubgraphBatch, nbits: int = 8, device=None):
     """Strategy III on a tile-cache hit: features-only compound transfer."""
     buf, meta = pack_feats(batch, nbits)
-    dbuf = jax.device_put(buf, device)
-    packed = unpack_feats(dbuf, n=meta["n"], nbits=meta["nbits"],
-                          wpf=meta["wpf"])
+    with spans.span("transfer"):
+        dbuf = jax.device_put(buf, device)
+        packed = unpack_feats(dbuf, n=meta["n"], nbits=meta["nbits"],
+                              wpf=meta["wpf"])
     return packed, meta
 
 

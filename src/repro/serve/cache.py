@@ -149,19 +149,10 @@ def compose_entries(entries: list[TileEntry], offsets: list[int],
 class TileCache:
     """LRU fingerprint -> :class:`TileEntry` map with hit/miss accounting.
 
-    Two accounting levels, kept separate so benchmark hit rates stay
-    honest:
-
-      per-key (``hits``/``misses``/``hit_rate``) — individual ``get``
-      lookups, i.e. per-subgraph under composition keying.
-
-      per-batch (``note_batch``: ``full_hits``/``partial_hits``/
-      ``full_misses``) — a *full* hit means every member of a coalesced
-      batch was cached (the batch ships features only); a *partial* hit
-      means some members were cached (their pack+occupancy work was
-      skipped, but the batch still ships its compound buffer for the
-      missing members). Reporting partial composition as "hit" would
-      overstate the transfer savings.
+    ``hits``/``misses``/``hit_rate`` count individual ``get`` lookups,
+    i.e. per subgraph under composition keying. The per-batch outcome
+    (full, partial or no hit) is the engine's: ``ServeStats.cache_hits``,
+    ``cache_misses`` and ``cache_partial_hits``.
 
     Eviction is bounded two ways: ``capacity`` counts entries (the
     fallback bound), ``cache_bytes`` bounds RESIDENT BYTES — entries vary
@@ -196,9 +187,6 @@ class TileCache:
         self.hits = 0
         self.misses = 0
         self.evictions = 0
-        self.full_hits = 0
-        self.partial_hits = 0
-        self.full_misses = 0
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -266,17 +254,6 @@ class TileCache:
             n_bytes += self._forget(k, self._entries.pop(k))
         return len(doomed), n_bytes
 
-    def note_batch(self, n_cached: int, n_members: int) -> None:
-        """Record one coalesced batch's composition outcome."""
-        if n_members <= 0:
-            return
-        if n_cached >= n_members:
-            self.full_hits += 1
-        elif n_cached > 0:
-            self.partial_hits += 1
-        else:
-            self.full_misses += 1
-
     def clear(self) -> None:
         self._entries.clear()
         self._replica_bytes.clear()
@@ -286,11 +263,6 @@ class TileCache:
     def hit_rate(self) -> float:
         total = self.hits + self.misses
         return self.hits / total if total else 0.0
-
-    @property
-    def full_hit_rate(self) -> float:
-        total = self.full_hits + self.partial_hits + self.full_misses
-        return self.full_hits / total if total else 0.0
 
     def nbytes(self) -> int:
         return sum(e.nbytes() for e in self._entries.values())

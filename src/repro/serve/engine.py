@@ -68,7 +68,7 @@ from repro.graph.batching import SubgraphBatch
 from repro.graph.packing import (compound_nbytes, transfer_packed,
                                  transfer_packed_feats)
 from repro.models import gnn
-from repro.perf import report
+from repro.perf import report, spans
 from repro.serve.cache import TileCache, TileEntry, compose_entries
 from repro.serve.chaos import ReplicaFault
 from repro.serve.queue import (AdmissionPolicy, CoalescedBatch, MicroBatcher,
@@ -133,11 +133,13 @@ class ServeStats:
     # batches executed per backing device ("default" = no mesh): shows
     # which devices a replica fleet actually ran on
     device_batches: dict = dataclasses.field(default_factory=dict)
-    # per-batch compute latency (timer stopped AFTER device sync),
-    # per-request queue->result latency, and per-request queue-wait
-    # (submit -> coalesce); all three share the same bounded rolling
-    # window (STATS_WINDOW) so a long-running server reports recent
-    # percentiles without growing per request
+    # per-batch latency (from the coalesced plan to its logits being
+    # ready on the device: the host's compose, pack, transfer and
+    # dispatch as well as the device's work; the serve.* spans of
+    # repro.perf.spans split it), per-request queue->result latency, and
+    # per-request queue-wait (submit -> coalesce); all three share the
+    # same bounded rolling window (STATS_WINDOW) so a long-running server
+    # reports recent percentiles without growing per request
     batch_latencies_s: collections.deque = dataclasses.field(
         default_factory=lambda: collections.deque(maxlen=STATS_WINDOW))
     request_latencies_s: collections.deque = dataclasses.field(
@@ -466,40 +468,47 @@ class GNNServer:
         retried batch returns {} this call and completes on a later step;
         it is never silently dropped.
         """
-        plan = self.batcher.next_plan()
-        if plan is None:
+        if not self.batcher:
             return {}
-        rep = plan.replica if plan.replica is not None else 0
-        self._routed_load[rep] -= len(plan.requests)
-        if self._routed_load[rep] <= 0:
-            self._routed_load.pop(rep, None)
-        t0 = time.perf_counter()
-        try:
-            if self._chaos is not None:
-                self._chaos.at_execute(rep, self.stats.batches)
-            logits, entry = self._execute_plan(plan, rep)
-            logits.block_until_ready()  # latency = compute, not dispatch
-        except ReplicaFault as fault:
-            self._retry_after_fault(plan, fault)
-            return {}
-        t1 = time.perf_counter()
-        self._observe_replica(rep, t1 - t0)
-        # queue-wait accounts on SUCCESS only: a faulted batch's requests
-        # stay queued and would double-count their wait on the retry
-        for r in plan.requests:
-            if r.t_enqueue is not None:
-                self.stats.queue_wait_s.append(t0 - r.t_enqueue)
-        self._account(plan.batch, entry, t1 - t0)
-        out = {}
-        lg = np.asarray(logits)
-        for req_id, off, n in plan.spans:
-            span = lg[off:off + n]
-            out[req_id] = (np.argmax(span, axis=-1), span)
-            self.stats.requests += 1
-        for r in plan.requests:
-            if r.t_enqueue is not None:
-                self.stats.request_latencies_s.append(t1 - r.t_enqueue)
-        return out
+        with spans.span("serve.step"):
+            with spans.span("serve.coalesce"):
+                plan = self.batcher.next_plan()
+            rep = plan.replica if plan.replica is not None else 0
+            self._routed_load[rep] -= len(plan.requests)
+            if self._routed_load[rep] <= 0:
+                self._routed_load.pop(rep, None)
+            t0 = time.perf_counter()
+            try:
+                if self._chaos is not None:
+                    self._chaos.at_execute(rep, self.stats.batches)
+                logits, entry = self._execute_plan(plan, rep)
+                # the latency stops at ready logits: host work and device
+                # compute both (see ServeStats.batch_latencies_s)
+                with spans.span("serve.device_wait"):
+                    logits.block_until_ready()
+            except ReplicaFault as fault:
+                self._retry_after_fault(plan, fault)
+                return {}
+            t1 = time.perf_counter()
+            self._observe_replica(rep, t1 - t0)
+            # queue-wait accounts on SUCCESS only: a faulted batch's
+            # requests stay queued and would double-count their wait on
+            # the retry
+            for r in plan.requests:
+                if r.t_enqueue is not None:
+                    self.stats.queue_wait_s.append(t0 - r.t_enqueue)
+            self._account(plan.batch, entry, t1 - t0)
+            out = {}
+            with spans.span("serve.readback"):
+                lg = np.asarray(logits)
+                for req_id, off, n in plan.spans:
+                    rows = lg[off:off + n]
+                    out[req_id] = (np.argmax(rows, axis=-1), rows)
+                    self.stats.requests += 1
+            for r in plan.requests:
+                if r.t_enqueue is not None:
+                    self.stats.request_latencies_s.append(t1 - r.t_enqueue)
+            return out
 
     def drain(self, return_logits: bool = False) -> dict:
         """Run until the queue is empty; results by req_id.
@@ -810,7 +819,6 @@ class GNNServer:
         keys = [("sub", r.fingerprint, rep) for r in plan.requests]
         entries = [self.cache.get(k) for k in keys]
         n_cached = sum(e is not None for e in entries)
-        self.cache.note_batch(n_cached, len(entries))
         offsets = [off for _, off, _ in plan.spans]
         l2_key = (tuple(r.fingerprint for r in plan.requests),
                   batch.n_nodes, rep)
@@ -826,31 +834,37 @@ class GNNServer:
             self.stats.cache_misses += 1
             if n_cached:
                 self.stats.cache_partial_hits += 1
-            for i, (e, key) in enumerate(zip(entries, keys)):
-                if e is not None:
-                    continue
-                off = offsets[i]
-                n_sub = _ceil_to(plan.spans[i][2], self._align)
-                sub_adj = jax.lax.dynamic_slice(adj, (off, off),
-                                                (n_sub, n_sub))
-                entries[i] = self._build_entry(sub_adj)
-                self.cache.put(key, entries[i])
-        entry = self._composed.get(l2_key)
-        if entry is None:
-            tm, tw = self._tile_shape
-            entry = compose_entries(entries, offsets, batch.n_nodes, tm, tw)
-            self._composed[l2_key] = entry
-            while len(self._composed) > self._composed_cap:
-                self._composed.popitem(last=False)
-        else:
-            self._composed.move_to_end(l2_key)
+            with spans.span("serve.tile_build"):
+                for i, (e, key) in enumerate(zip(entries, keys)):
+                    if e is not None:
+                        continue
+                    off = offsets[i]
+                    n_sub = _ceil_to(plan.spans[i][2], self._align)
+                    sub_adj = jax.lax.dynamic_slice(adj, (off, off),
+                                                    (n_sub, n_sub))
+                    entries[i] = self._build_entry(sub_adj)
+                    self.cache.put(key, entries[i])
+        with spans.span("serve.compose") as sp:
+            entry = self._composed.get(l2_key)
+            sp["composed_hit"] = int(entry is not None)
+            if entry is None:
+                tm, tw = self._tile_shape
+                entry = compose_entries(entries, offsets, batch.n_nodes,
+                                        tm, tw)
+                self._composed[l2_key] = entry
+                while len(self._composed) > self._composed_cap:
+                    self._composed.popitem(last=False)
+            else:
+                self._composed.move_to_end(l2_key)
         return self._forward(device, entry, packed, meta), entry
 
     def _forward(self, device, entry: TileEntry, packed, meta):
         key = str(device) if device is not None else "default"
         self.stats.device_batches[key] = \
             self.stats.device_batches.get(key, 0) + 1
-        return self._fwd(*self._forward_args(device, entry, packed, meta))
+        with spans.span("serve.dispatch"):
+            return self._fwd(*self._forward_args(device, entry, packed,
+                                                 meta))
 
     def _forward_args(self, device, entry: TileEntry, packed, meta) -> tuple:
         pol = self._policy_for_n(entry.adj.shape[0])

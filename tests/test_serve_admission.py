@@ -163,7 +163,9 @@ def test_shuffled_coalescing_order_hits_and_is_bit_identical(setup):
         warm.submit(_fresh(r))
     warm.drain()
     hits0, misses0 = warm.cache.hits, warm.cache.misses
-    assert warm.cache.full_misses > 0 and warm.cache.full_hits == 0
+    st = warm.stats
+    assert st.cache_misses - st.cache_partial_hits > 0 and st.cache_hits == 0
+    cold_batches = st.batches
 
     rng = np.random.default_rng(3)
     for rnd in range(2):
@@ -186,8 +188,8 @@ def test_shuffled_coalescing_order_hits_and_is_bit_identical(setup):
     assert warm.cache.hits == hits0 + 2 * len(reqs)
     # batch-level: every shuffled batch was a FULL hit (features-only
     # transfer), even though the groupings never matched the cold wave's
-    assert warm.cache.partial_hits == 0
-    assert warm.cache.full_hits == warm.stats.cache_hits > 0
+    assert st.cache_partial_hits == 0 and st.cache_misses == cold_batches
+    assert st.cache_hits == st.batches - cold_batches > 0
 
 
 def test_partial_composition_hit_accounting(setup):
@@ -201,22 +203,23 @@ def test_partial_composition_hit_accounting(setup):
     # warm exactly one subgraph (alone in its batch)
     srv.submit(_fresh(reqs[0]))
     srv.drain()
-    assert (srv.cache.full_misses, srv.cache.partial_hits,
-            srv.cache.full_hits) == (1, 0, 0)
+    st = srv.stats
+    assert (st.cache_misses - st.cache_partial_hits, st.cache_partial_hits,
+            st.cache_hits) == (1, 0, 0)
     # now coalesce it with an unseen subgraph -> partial composition hit
     srv.submit(_fresh(reqs[0]))
     srv.submit(_fresh(reqs[1]))
     out = srv.drain()
     assert len(out) == 2
-    assert srv.cache.partial_hits == 1 and srv.cache.full_hits == 0
-    assert srv.stats.cache_partial_hits == 1
-    assert srv.stats.cache_hits == 0  # partial is NOT a (transfer) hit
+    assert st.cache_partial_hits == 1
+    assert st.cache_hits == 0  # partial is NOT a (transfer) hit
     # repeat the same pair -> now a full hit
     srv.submit(_fresh(reqs[0]))
     srv.submit(_fresh(reqs[1]))
     srv.drain()
-    assert srv.cache.full_hits == 1
-    assert srv.cache.full_hit_rate == pytest.approx(1 / 3)
+    assert st.cache_hits == 1
+    assert st.cache_hits / (st.cache_hits + st.cache_misses) == \
+        pytest.approx(1 / 3)
 
 
 def test_compose_entries_matches_whole_batch_build(setup):
