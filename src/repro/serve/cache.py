@@ -22,6 +22,13 @@ by the member's word offset ``off // 32``.
 A repeat subgraph therefore hits the cache in ANY coalescing order; under
 per-group keying a novel ordering was a guaranteed miss.
 
+Composition dispatches 1 + members compiled programs: one init program
+makes the batch's empty arrays on the members' device, then one placement
+program per member writes that member in, its offset a traced int32. The
+batch arrays are donated down that chain; the members' cached arrays never
+are. The compiled set is bounded by buckets x aligned member sizes x SGT
+presence, never by offsets or member order (:func:`compose_compiles`).
+
 TC-GNN (PAPERS.md) motivates the same tile-occupancy-centric view of
 sparse adjacencies; here the occupancy map IS the cached object.
 """
@@ -29,11 +36,13 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-__all__ = ["TileEntry", "TileCache", "compose_entries"]
+__all__ = ["TileEntry", "TileCache", "compose_entries", "compose_compiles"]
 
 
 @dataclasses.dataclass
@@ -67,6 +76,69 @@ class TileEntry:
         return n
 
 
+def _empty_batch(n_pad: int, tm: int, tw: int, adj_dtype, have_sgt: bool,
+                 device):
+    """The batch's arrays before any member is placed, made on ``device``:
+    padding rows have degree 0 (inverse degree 1), every tile is empty."""
+    mt, kt, wt = n_pad // tm, n_pad // (32 * tw), n_pad // 32
+    sgt = ((jnp.zeros((mt, wt), jnp.int32), jnp.zeros((mt,), jnp.int32))
+           if have_sgt else None)
+    arrays = (jnp.zeros((n_pad, n_pad), adj_dtype),
+              jnp.ones((n_pad, 1), jnp.float32),
+              jnp.zeros((n_pad, wt), jnp.uint32),
+              jnp.zeros((mt, kt), jnp.int32),
+              jnp.zeros((mt, kt), jnp.int32),
+              jnp.zeros((mt,), jnp.int32), sgt)
+    return jax.device_put(arrays, jax.sharding.SingleDeviceSharding(device))
+
+
+def _shift(idx, counts, base):
+    """A member's tile-id remap moved to its batch position; slots past a
+    row's count stay 0, as the whole-batch build leaves them."""
+    mask = jnp.arange(idx.shape[1])[None, :] < counts[:, None]
+    return jnp.where(mask, idx + base, 0).astype(jnp.int32)
+
+
+def _place_member(batch, member, off, tm: int, tw: int):
+    """Write one member's arrays into the batch's at node offset ``off``."""
+    adj, inv_deg, a_packed, occ, idx, counts, sgt = batch
+    m_adj, m_inv, m_packed, m_occ, m_idx, m_counts, m_sgt = member
+    dus = jax.lax.dynamic_update_slice
+    r0, k0 = off // tm, off // (32 * tw)
+    adj = dus(adj, m_adj, (off, off))
+    inv_deg = dus(inv_deg, m_inv, (off, 0))
+    a_packed = dus(a_packed, m_packed, (off, off // 32))
+    occ = dus(occ, m_occ, (r0, k0))
+    idx = dus(idx, _shift(m_idx, m_counts, k0), (r0, 0))
+    counts = dus(counts, m_counts, (r0,))
+    if sgt is not None:
+        # the SGT word-column remap shifts by the member's word offset
+        (s_idx, s_counts), (m_sidx, m_scounts) = sgt, m_sgt
+        sgt = (dus(s_idx, _shift(m_sidx, m_scounts, off // 32), (r0, 0)),
+               dus(s_counts, m_scounts, (r0,)))
+    return adj, inv_deg, a_packed, occ, idx, counts, sgt
+
+
+# The init program has no array input, so its device is a static argument;
+# placement runs where its inputs live. Only the batch arrays are donated.
+_init = jax.jit(_empty_batch, static_argnums=(0, 1, 2, 3, 4, 5))
+_place = jax.jit(_place_member, static_argnums=(3, 4), donate_argnums=(0,))
+
+
+@functools.lru_cache(maxsize=1024)
+def _offset_on(off: int, device) -> jax.Array:
+    """A member offset as a device scalar, made once per (offset, device):
+    offsets are few (multiples of the alignment below the top bucket), and
+    a fresh host scalar per placement would cost a transfer each time."""
+    return jax.device_put(np.int32(off), device)
+
+
+def compose_compiles() -> int:
+    """Compiled init and placement variants, shared by every caller in
+    the process."""
+    return _init._cache_size() + _place._cache_size()
+
+
 def compose_entries(entries: list[TileEntry], offsets: list[int],
                     n_pad: int, block_m: int, block_w: int) -> TileEntry:
     """Assemble a block-diagonal batch entry from per-subgraph entries.
@@ -80,6 +152,9 @@ def compose_entries(entries: list[TileEntry], offsets: list[int],
     occupancy/compact rows are exactly the member's own (k-tile ids
     shifted by the member's column-tile offset), and ``s_max`` is the max
     of the members' host-side counts — no device sync at coalesce time.
+
+    Dispatches 1 + len(entries) compiled programs (module docstring); the
+    result lives on the members' device.
     """
     if not entries:
         raise ValueError("compose_entries needs at least one entry")
@@ -90,48 +165,25 @@ def compose_entries(entries: list[TileEntry], offsets: list[int],
             f"batch n_pad={n_pad} not a multiple of the tile grid "
             f"(block_m={tm}, {step} node columns per k-tile); pad the "
             f"bucket to lcm({tm}, {step})")
-    mt, kt = n_pad // tm, n_pad // step
-    wt = n_pad // 32
-    adj = jnp.zeros((n_pad, n_pad), entries[0].adj.dtype)
-    inv_deg = jnp.ones((n_pad, 1), jnp.float32)  # padding rows: deg 0
-    a_packed = jnp.zeros((n_pad, n_pad // 32), jnp.uint32)
-    occ = jnp.zeros((mt, kt), jnp.int32)
-    idx = jnp.zeros((mt, kt), jnp.int32)
-    counts = jnp.zeros((mt,), jnp.int32)
-    # SGT word-column remap composes by the same shifting, at word
-    # granularity (off // 32); only when every member carries it
-    have_sgt = all(e.sgt_idx is not None for e in entries)
-    sgt_idx = jnp.zeros((mt, wt), jnp.int32) if have_sgt else None
-    sgt_counts = jnp.zeros((mt,), jnp.int32) if have_sgt else None
-    tiles_nonzero, s_max, sgt_w = 0, 0, 0
     for e, off in zip(entries, offsets):
         n_sub = e.adj.shape[0]
         if off % tm or off % step or off + n_sub > n_pad:
             raise ValueError(
                 f"member offset {off} (size {n_sub}) not tile-aligned "
                 f"inside n_pad={n_pad}; use MicroBatcher(align=...)")
-        adj = jax.lax.dynamic_update_slice(adj, e.adj, (off, off))
-        inv_deg = jax.lax.dynamic_update_slice(inv_deg, e.inv_deg, (off, 0))
-        a_packed = jax.lax.dynamic_update_slice(a_packed, e.a_packed,
-                                                (off, off // 32))
-        r0, k0 = off // tm, off // step
-        occ = jax.lax.dynamic_update_slice(occ, e.occupancy, (r0, k0))
-        kt_sub = e.compact_idx.shape[1]
-        mask = jnp.arange(kt_sub)[None, :] < e.compact_counts[:, None]
-        shifted = jnp.where(mask, e.compact_idx + k0, 0).astype(jnp.int32)
-        idx = jax.lax.dynamic_update_slice(idx, shifted, (r0, 0))
-        counts = jax.lax.dynamic_update_slice(counts, e.compact_counts, (r0,))
-        if have_sgt:
-            w0 = off // 32
-            wt_sub = e.sgt_idx.shape[1]
-            smask = jnp.arange(wt_sub)[None, :] < e.sgt_counts[:, None]
-            sshift = jnp.where(smask, e.sgt_idx + w0, 0).astype(jnp.int32)
-            sgt_idx = jax.lax.dynamic_update_slice(sgt_idx, sshift, (r0, 0))
-            sgt_counts = jax.lax.dynamic_update_slice(sgt_counts,
-                                                      e.sgt_counts, (r0,))
-            sgt_w = max(sgt_w, e.sgt_w)
-        tiles_nonzero += e.occ_stats["tiles_nonzero"]
-        s_max = max(s_max, e.s_max)
+    # SGT word-column remap composes only when every member carries it
+    have_sgt = all(e.sgt_idx is not None for e in entries)
+    device, = entries[0].adj.devices()
+    batch = _init(n_pad, tm, tw, entries[0].adj.dtype, have_sgt, device)
+    for e, off in zip(entries, offsets):
+        sgt = (e.sgt_idx, e.sgt_counts) if have_sgt else None
+        member = (e.adj, e.inv_deg, e.a_packed, e.occupancy, e.compact_idx,
+                  e.compact_counts, sgt)
+        batch = _place(batch, member, _offset_on(off, device), tm, tw)
+    adj, inv_deg, a_packed, occ, idx, counts, sgt = batch
+    sgt_idx, sgt_counts = sgt or (None, None)
+    mt, kt = n_pad // tm, n_pad // step
+    tiles_nonzero = sum(e.occ_stats["tiles_nonzero"] for e in entries)
     total = mt * kt
     occ_stats = {
         "tiles_total": total,
@@ -142,8 +194,10 @@ def compose_entries(entries: list[TileEntry], offsets: list[int],
     }
     return TileEntry(adj=adj, inv_deg=inv_deg, a_packed=a_packed,
                      occupancy=occ, compact_idx=idx, compact_counts=counts,
-                     occ_stats=occ_stats, s_max=s_max, sgt_idx=sgt_idx,
-                     sgt_counts=sgt_counts, sgt_w=sgt_w)
+                     occ_stats=occ_stats,
+                     s_max=max(e.s_max for e in entries),
+                     sgt_idx=sgt_idx, sgt_counts=sgt_counts,
+                     sgt_w=max(e.sgt_w for e in entries) if have_sgt else 0)
 
 
 class TileCache:

@@ -69,7 +69,8 @@ from repro.graph.packing import (compound_nbytes, transfer_packed,
                                  transfer_packed_feats)
 from repro.models import gnn
 from repro.perf import report, spans
-from repro.serve.cache import TileCache, TileEntry, compose_entries
+from repro.serve.cache import (TileCache, TileEntry, compose_compiles,
+                               compose_entries)
 from repro.serve.chaos import ReplicaFault
 from repro.serve.queue import (AdmissionPolicy, CoalescedBatch, MicroBatcher,
                                SubgraphRequest, _ceil_to,
@@ -395,6 +396,13 @@ class GNNServer:
         """Compiled forward variants (one per shape bucket per device)."""
         cache_size = getattr(self._fwd, "_cache_size", None)
         return int(cache_size()) if cache_size is not None else -1
+
+    @property
+    def n_compose_compiles(self) -> int:
+        """Compiled composition programs (init per bucket and device,
+        placement per bucket and aligned member size); the programs are
+        shared by every server in the process."""
+        return compose_compiles()
 
     @property
     def align(self) -> int:
@@ -847,6 +855,7 @@ class GNNServer:
         with spans.span("serve.compose") as sp:
             entry = self._composed.get(l2_key)
             sp["composed_hit"] = int(entry is not None)
+            sp["programs"] = 0 if entry is not None else 1 + len(entries)
             if entry is None:
                 tm, tw = self._tile_shape
                 entry = compose_entries(entries, offsets, batch.n_nodes,

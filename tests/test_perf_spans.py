@@ -153,7 +153,8 @@ def test_a_cached_batch_records_each_phase_once(served):
     assert sorted(phases) == sorted(PHASES)
     assert all(len(v) == 1 for v in phases.values())
     compose, = phases["serve.compose"]
-    assert compose.attrs == {"composed_hit": 1}  # the same order again
+    # the same order again: a memo hit dispatches no program
+    assert compose.attrs == {"composed_hit": 1, "programs": 0}
     for (r,) in phases.values():
         assert step.t0 <= r.t0 <= r.t1 <= step.t1
 
@@ -167,4 +168,5 @@ def test_a_batch_with_misses_records_the_tile_build(served):
     assert len(phases["serve.tile_build"]) == 1
     assert srv.cache.misses == misses + 1  # the one member it built
     compose, = phases["serve.compose"]
-    assert compose.attrs == {"composed_hit": 0}
+    # a miss: the init program, then one placement per member
+    assert compose.attrs == {"composed_hit": 0, "programs": 3}
