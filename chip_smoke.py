@@ -15,7 +15,8 @@ process, and checks the answers by the repository's exactness invariant
   train  - five steps of the integer training path
            (``repro.train.trainer``, as ``python -m repro.launch.train
            --int-path``) on the same graph with ``backend="pallas"`` and
-           8-bit integer gradients; the losses must be finite and equal to
+           8-bit integer gradients, for the paper's GCN and for its GIN
+           (3 layers, hidden 64); the losses must be finite and equal to
            the same steps on ``xla_dot``.
 
 ``--chips 4`` runs only the replica phase: four serving replicas, one per
@@ -47,6 +48,7 @@ PARTS = 1500        # the paper's Cluster-GCN part count for this graph
 FEAT_BITS = 8
 JUMPS = ("none", "compact", "sgt")
 TRAIN_STEPS = 5
+TRAIN_ARCHS = ("qgtc-gcn", "qgtc-gin")
 
 
 def _log(msg: str) -> None:
@@ -82,6 +84,18 @@ def _check_equal(name: str, got: list, ref: list) -> None:
             f"{len(got)} answered")
 
 
+def _config(arch: str, data):
+    """The paper's GNN ``arch`` (``configs/qgtc_gnn.py``) sized to ``data``."""
+    import dataclasses
+
+    from repro.configs.qgtc_gnn import GNN_CONFIGS
+
+    return dataclasses.replace(GNN_CONFIGS[arch],
+                               in_dim=data.features.shape[1],
+                               n_classes=data.n_classes, x_bits=FEAT_BITS,
+                               w_bits=FEAT_BITS)
+
+
 def _setup(seed: int):
     import jax
     from repro.graph import datasets, partition
@@ -92,8 +106,7 @@ def _setup(seed: int):
     data = datasets.load(DATASET, scale=1.0, seed=seed)
     parts = partition.partition(data.csr, PARTS)
     reqs = requests_from_partitions(data, parts)
-    cfg = gnn.GNNConfig.paper_gcn(data.features.shape[1], data.n_classes,
-                                  x_bits=FEAT_BITS, w_bits=FEAT_BITS)
+    cfg = _config("qgtc-gcn", data)
     qparams = gnn.quantize_params(
         gnn.init_params(jax.random.PRNGKey(seed), cfg), cfg)
     _log(f"data {DATASET}: {data.csr.n} nodes, "
@@ -141,9 +154,10 @@ def serve_phase(reqs, cfg, qparams) -> None:
                  f"(smoke numbers, compiles included)")
 
 
-def train_phase(data, parts, cfg) -> None:
+def train_phase(data, parts, arch: str) -> None:
     from repro.train import trainer
 
+    cfg = _config(arch, data)
     losses = {}
     for backend in ("pallas", "xla_dot"):
         tcfg = trainer.TrainConfig(steps=TRAIN_STEPS, log_every=1,
@@ -152,15 +166,16 @@ def train_phase(data, parts, cfg) -> None:
         t0 = time.perf_counter()
         _, _, hist = trainer.train(data, parts, cfg, tcfg)
         losses[backend] = [h["loss"] for h in hist]
-        _log(f"train int_bitserial backend={backend}: losses "
+        _log(f"train {arch} int_bitserial backend={backend}: losses "
              f"{losses[backend]}, wall {time.perf_counter() - t0:.3f} s "
              f"(smoke number, compiles included)")
     got, ref = losses["pallas"], losses["xla_dot"]
     if len(got) != TRAIN_STEPS or not np.all(np.isfinite(got)):
-        raise AssertionError(f"train: expected {TRAIN_STEPS} finite "
+        raise AssertionError(f"train {arch}: expected {TRAIN_STEPS} finite "
                              f"losses, got {got}")
     if got != ref:
-        raise AssertionError(f"train: pallas losses {got} != xla_dot {ref}")
+        raise AssertionError(f"train {arch}: pallas losses {got} != xla_dot "
+                             f"{ref}")
 
 
 def replica_phase(reqs, cfg, qparams, n_chips: int) -> None:
@@ -255,7 +270,8 @@ def main(argv=None) -> int:
         replica_phase(reqs, cfg, qparams, args.chips)
     else:
         serve_phase(reqs, cfg, qparams)
-        train_phase(data, parts, cfg)
+        for arch in TRAIN_ARCHS:
+            train_phase(data, parts, arch)
     _log(f"all phases passed in {time.perf_counter() - t0:.3f} s")
     print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0

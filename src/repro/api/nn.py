@@ -245,38 +245,46 @@ def blocked_agg_full(adjb, row_idx, rsrc, rdst, v, s, *, backend=None,
 
 @partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3, 4, 5))
 def _qgraph_conv_train(x_bits, grad_bits, sr, backend, policy, s_maxes,
-                       u, adjb, row_idx, rsrc, rdst, inv_deg, deg, deg_in,
-                       tiles, key):
+                       u, uq, qpu, eps, adjb, row_idx, rsrc, rdst, inv_deg,
+                       deg, deg_in, tiles, key):
     out, _ = _qgc_fwd(x_bits, grad_bits, sr, backend, policy, s_maxes,
-                      u, adjb, row_idx, rsrc, rdst, inv_deg, deg, deg_in,
-                      tiles, key)
+                      u, uq, qpu, eps, adjb, row_idx, rsrc, rdst, inv_deg,
+                      deg, deg_in, tiles, key)
     return out
 
 
 def _qgc_fwd(x_bits, grad_bits, sr, backend, policy, s_maxes,
-             u, adjb, row_idx, rsrc, rdst, inv_deg, deg, deg_in, tiles, key):
+             u, uq, qpu, eps, adjb, row_idx, rsrc, rdst, inv_deg, deg,
+             deg_in, tiles, key):
     from repro.kernels import ops as kops
 
     ku = kg = None
     if sr and key is not None:
         ku, kg = jax.random.split(key)
-    qpu = calibrate(u, x_bits)
-    uq = (quantize_stochastic(u, qpu, ku) if sr and ku is not None
-          else quantize(u, qpu))
+    if uq is None:
+        qpu = calibrate(u, x_bits)
+        uq = (quantize_stochastic(u, qpu, ku) if sr and ku is not None
+              else quantize(u, qpu))
     cnt = _blocked_agg(adjb, row_idx, uq, x_bits, backend, policy,
                        tiles, s_maxes)
     cnt = cnt + kops.edge_scatter_sum(uq, rsrc, rdst, u.shape[0])
     # dequant epilogue: sum_j u_dq[j] = scale*cnt + deg*zero; + self; scale
+    u_dq = dequantize(uq, qpu)
     out = (cnt.astype(jnp.float32) * qpu.scale + deg * qpu.zero
-           + dequantize(uq, qpu)) * inv_deg
-    res = (_in_range(u, qpu), adjb, row_idx, rsrc, rdst, inv_deg, deg_in, kg)
+           + u_dq) * inv_deg
+    if eps is not None:
+        # GIN's self term, a float epilogue over the same quantized u
+        out = out + eps * u_dq
+    res = (_in_range(u, qpu), adjb, row_idx, rsrc, rdst, inv_deg, deg_in,
+           eps, None if eps is None else u_dq, kg)
     return out, res
 
 
 def _qgc_bwd(x_bits, grad_bits, sr, backend, policy, s_maxes, res, g):
     from repro.kernels import ops as kops
 
-    u_mask, adjb, row_idx, rsrc, rdst, inv_deg, deg_in, kg = res
+    (u_mask, adjb, row_idx, rsrc, rdst, inv_deg, deg_in, eps, u_dq,
+     kg) = res
     gp = g * inv_deg
     n = gp.shape[0]
     # out = (A+I) @ u_dq * inv_deg  =>  du = (A^T+I) @ (g*inv_deg), STE-masked.
@@ -298,15 +306,20 @@ def _qgc_bwd(x_bits, grad_bits, sr, backend, policy, s_maxes, res, g):
     else:
         cnt = _blocked_agg(adjt, row_idx, gp, 0, backend, policy, None, None)
         gu = cnt + kops.edge_scatter_sum(gp, rdst, rsrc, n) + gp
+    geps = None
+    if eps is not None:
+        gu = gu + eps * g
+        geps = jnp.sum(g * u_dq)
     gu = jnp.where(u_mask, gu, 0.0)
-    return (gu, None, None, None, None, None, None, None, None, None)
+    return (gu, None, None, geps, None, None, None, None, None, None, None,
+            None, None)
 
 
 _qgraph_conv_train.defvjp(_qgc_fwd, _qgc_bwd)
 
 
-def qgraph_conv_train(u, art, *, x_bits=8, grad_bits=0, stochastic=False,
-                      key=None, backend=None, policy=None):
+def qgraph_conv_train(u, art, *, eps=None, x_bits=8, grad_bits=0,
+                      stochastic=False, key=None, backend=None, policy=None):
     """Trainable Â u aggregation over cached integer batch artifacts.
 
     ``art`` is a ``repro.train.intpath.IntBatchArtifacts``: the batch
@@ -323,13 +336,25 @@ def qgraph_conv_train(u, art, *, x_bits=8, grad_bits=0, stochastic=False,
     ``stochastic``); backward is ``(A^T + I) @ (g * inv_deg)`` with the STE
     mask from the forward calibration, run as an integer aggregation of the
     quantized cotangent when ``grad_bits > 0``.
+
+    ``u`` may be a float tensor or a pre-quantized ``(uq, QuantParams)``
+    pair, as in :func:`qlinear_train` (GIN's layer 0 aggregates the batch
+    features ``art.xq, art.qpx``; no gradient flows to them). ``eps``
+    (GIN's self weight, a float scalar) adds ``eps * dequant(uq)`` after
+    the epilogue, on the same quantized ``u``; its gradient is
+    ``sum(g * dequant(uq))``, and ``eps * g`` joins ``u``'s.
     """
     if stochastic and key is None:
         raise ValueError("stochastic=True requires a PRNG key")
+    uq = qpu = None
+    if isinstance(u, tuple):
+        uq, qpu = as_quantized(u, x_bits)
+        u = dequantize(uq, qpu)
     return _qgraph_conv_train(x_bits, grad_bits, bool(stochastic), backend,
-                              policy, art.s_maxes, u, art.adjb, art.row_idx,
-                              art.rem_src, art.rem_dst, art.inv_deg,
-                              art.deg, art.deg_in, art.tiles, key)
+                              policy, art.s_maxes, u, uq, qpu, eps, art.adjb,
+                              art.row_idx, art.rem_src, art.rem_dst,
+                              art.inv_deg, art.deg, art.deg_in, art.tiles,
+                              key)
 
 
 def wq_linear(x, wq, *, bias=None, out_dtype=jnp.bfloat16, backend=None,

@@ -16,16 +16,19 @@ GNN mode (``--gnn DATASET``): streams repeat subgraph traffic through the
 ``repro.serve.GNNServer`` continuous-batching engine (queue + shape
 buckets + tile cache, see docs/serve.md) under the ``repro.dist`` "serve"
 rule table, and prints the ServeStats summary (p50/p95 after device sync).
+``--arch`` then names the paper's GNN in ``configs/qgtc_gnn.py``
+(``qgtc-gcn``, the default, or ``qgtc-gin``).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b --smoke \
       --requests 12 --max-new 16 --wq-bits 4
   PYTHONPATH=src python -m repro.launch.serve --gnn ogbn-arxiv --scale \
-      0.008 --rounds 3
+      0.008 --rounds 3 [--arch qgtc-gin]
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -36,6 +39,7 @@ import numpy as np
 from repro import configs
 from repro.api import nn as qnn
 from repro.configs.base import smoke_config
+from repro.configs.qgtc_gnn import GNN_CONFIGS
 from repro.dist import sharding as shd
 from repro.launch.compile_cache import init_compile_cache
 from repro.launch.mesh import make_local_mesh
@@ -113,9 +117,10 @@ def serve_gnn(args) -> dict:
 
     data = datasets.load(args.gnn, scale=args.scale, seed=args.seed)
     parts = partition.partition(data.csr, args.parts)
-    cfg = gnn.GNNConfig.paper_gcn(data.features.shape[1], data.n_classes,
-                                  x_bits=args.feat_bits,
-                                  w_bits=args.feat_bits)
+    cfg = dataclasses.replace(GNN_CONFIGS[args.arch or "qgtc-gcn"],
+                              in_dim=data.features.shape[1],
+                              n_classes=data.n_classes,
+                              x_bits=args.feat_bits, w_bits=args.feat_bits)
     params = gnn.init_params(jax.random.PRNGKey(args.seed), cfg)
     qparams = gnn.quantize_params(params, cfg)
     reqs = requests_from_partitions(data, parts)
@@ -159,6 +164,7 @@ def serve_gnn(args) -> dict:
                   f"retried={st.requests_retried} "
                   f"retry_after={st.retry_after_s:.4f}s", flush=True)
     summary = server.stats.summary()
+    summary["model"] = cfg.model
     summary["n_compiles"] = server.n_compiles
     summary["tuned_policies"] = server.tuned_policies()
     summary["replicas"] = server.stats.replicas_live
@@ -176,7 +182,9 @@ def serve_gnn(args) -> dict:
 
 def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", help="LM architecture to serve")
+    ap.add_argument("--arch", help="LM architecture to serve; with --gnn, "
+                                   "the GNN (qgtc-gcn, the default, or "
+                                   "qgtc-gin)")
     ap.add_argument("--gnn", metavar="DATASET",
                     help="serve GNN subgraph traffic from this Table-1 "
                          "dataset instead of an LM")
@@ -232,8 +240,12 @@ def main(argv=None) -> dict:
                          "(hand-picked defaults), or a table file from "
                          "python -m repro.launch.sweep")
     args = ap.parse_args(argv)
-    if (args.arch is None) == (args.gnn is None):
-        ap.error("pass exactly one of --arch (LM) or --gnn (GNN)")
+    if args.gnn is None and (args.arch is None or args.arch in GNN_CONFIGS):
+        ap.error("pass --arch (LM), or --gnn DATASET with an optional "
+                 f"--arch in {sorted(GNN_CONFIGS)} (GNN)")
+    if args.gnn is not None and args.arch not in (None, *GNN_CONFIGS):
+        ap.error(f"--gnn serves one of {sorted(GNN_CONFIGS)}, not "
+                 f"--arch {args.arch}")
     if not 1 <= args.feat_bits <= 8:
         ap.error(f"--feat-bits must be in 1..8, got {args.feat_bits}")
     if args.wq_bits and not 1 <= args.wq_bits <= 8:

@@ -9,12 +9,12 @@ Each model has three execution paths sharing one parameter pytree:
                activations/weights, integer bit-serial GEMMs with float
                rescale epilogues (Algorithm 1 + §4.5). Hidden layers
                requantize; only the final layer emits full precision.
-  int_bitserial — the TRAINING twin of qgtc: same integer forward, but
-               differentiable (api.nn.qlinear_train / qgraph_conv_train
-               custom_vjps with STE backward, optional quantized gradients
-               + stochastic rounding) and fed by per-batch cached
-               IntBatchArtifacts (repro.train.intpath) instead of a dense
-               adjacency rebuilt every step.
+  int_bitserial — the TRAINING twin of qgtc, for GCN and GIN: same
+               integer forward, but differentiable (api.nn.qlinear_train /
+               qgraph_conv_train custom_vjps with STE backward, optional
+               quantized gradients + stochastic rounding) and fed by
+               per-batch cached IntBatchArtifacts (repro.train.intpath)
+               instead of a dense adjacency rebuilt every step.
 
 The qgtc path is built from the functional layers in ``repro.api.nn``
 (``qlinear`` / ``qgraph_conv``), which dispatch through the repro.api
@@ -180,34 +180,41 @@ def forward_int(
 ) -> jax.Array:
     """Differentiable integer forward over cached batch artifacts.
 
-    The float-parameter twin of :func:`forward_qgtc`: weights are quantized
-    in-trace by the custom_vjp layers (so ``jax.grad`` reaches them through
-    STE), activations flow quantized through the same bitserial GEMMs, and
-    the aggregation runs blocked over ``art``'s per-partition diagonal
-    blocks + cross-block edge remainder. Layer 0 consumes the batch
-    features pre-quantized once in ``art`` (``xq, qpx``) — no per-step
-    feature requant. ``grad_bits > 0`` quantizes the backward GEMMs too;
-    ``stochastic`` enables stochastic rounding (requires ``key``, split
-    per layer so no two quantizers share randomness).
+    The float-parameter twin of :func:`forward_qgtc`, for both models:
+    weights are quantized in-trace by the custom_vjp layers (so
+    ``jax.grad`` reaches them through STE), activations flow quantized
+    through the same bitserial GEMMs, and the aggregation runs blocked over
+    ``art``'s per-partition diagonal blocks + cross-block edge remainder.
+    Layer 0 consumes the batch features pre-quantized once in ``art``
+    (``xq, qpx``) — no per-step feature requant. GIN's ``eps·h`` self term
+    is a float epilogue of the aggregation over the same quantized ``h``,
+    so ``eps`` gets its gradient as on the fake-quant path. ``grad_bits >
+    0`` quantizes the backward GEMMs too; ``stochastic`` enables
+    stochastic rounding (requires ``key``, split per quantizing layer so
+    no two quantizers share randomness).
     """
-    if cfg.model != "gcn":
-        raise NotImplementedError(
-            "int_bitserial training path covers cluster-GCN; GIN still "
-            "trains via the fake-quant path (its eps-weighted self term "
-            "needs a float epilogue the train kernels do not fuse yet)")
     mm = dict(backend=backend, policy=policy)
-    keys = (jax.random.split(key, cfg.layers * 2)
-            if key is not None else [None] * (cfg.layers * 2))
+    q = dict(x_bits=cfg.x_bits, grad_bits=grad_bits, stochastic=stochastic)
+    per_layer = 3 if cfg.model == "gin" else 2
+    keys = (jax.random.split(key, cfg.layers * per_layer)
+            if key is not None else [None] * (cfg.layers * per_layer))
     h = (art.xq, art.qpx)
     for l in range(cfg.layers):
         p = params[f"layer{l}"]
-        u = qnn.qlinear_train(h, p["w"], p["b"], x_bits=cfg.x_bits,
-                              w_bits=cfg.w_bits, grad_bits=grad_bits,
-                              stochastic=stochastic, key=keys[2 * l], **mm)
-        u = constrain(u, "gnn_nodes", None)
-        h = qnn.qgraph_conv_train(u, art, x_bits=cfg.x_bits,
-                                  grad_bits=grad_bits, stochastic=stochastic,
-                                  key=keys[2 * l + 1], **mm)
+        k = keys[per_layer * l:per_layer * (l + 1)]
+        if cfg.model == "gin":
+            a = qnn.qgraph_conv_train(h, art, eps=p["eps"], key=k[0], **q,
+                                      **mm)
+            a = constrain(a, "gnn_nodes", None)
+            u = jax.nn.relu(qnn.qlinear_train(
+                a, p["w1"], p["b1"], w_bits=cfg.w_bits, key=k[1], **q, **mm))
+            h = qnn.qlinear_train(u, p["w2"], p["b2"], w_bits=cfg.w_bits,
+                                  key=k[2], **q, **mm)
+        else:
+            u = qnn.qlinear_train(h, p["w"], p["b"], w_bits=cfg.w_bits,
+                                  key=k[0], **q, **mm)
+            u = constrain(u, "gnn_nodes", None)
+            h = qnn.qgraph_conv_train(u, art, key=k[1], **q, **mm)
         if l != cfg.layers - 1:
             h = jax.nn.relu(h)
     return h

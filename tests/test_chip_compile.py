@@ -120,6 +120,25 @@ def test_backward_bitserial_mm_compiles(one_chip, shape):
     assert "tpu_custom_call" in text
 
 
+GIN_HIDDEN, N_CLASSES = 64, 40
+
+
+@pytest.mark.parametrize("m,k,n,a_bits", [
+    (N_PAD, N_PAD, GIN_HIDDEN, 1),       # aggregation at the hidden width
+    (N_PAD, IN_DIM, GIN_HIDDEN, BITS),   # layer 0's first MLP GEMM
+    (N_PAD, GIN_HIDDEN, GIN_HIDDEN, BITS),
+    (N_PAD, GIN_HIDDEN, N_CLASSES, BITS)])  # the last layer's second GEMM
+def test_gin_forward_gemms_compile(one_chip, m, k, n, a_bits):
+    """The paper GIN's (hidden 64) integer GEMMs, as ``api.nn`` calls them."""
+    pol = _chip_policy()
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    text = _compile_text(
+        lambda a, b: api.bitserial_mm(a, b, a_bits, BITS, backend="pallas",
+                                      policy=pol),
+        i32(m, k), i32(k, n))
+    assert "tpu_custom_call" in text
+
+
 # ------------------------------------------------- no fallback hides the chip
 
 def _load_chip_smoke():
