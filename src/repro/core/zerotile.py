@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["tile_occupancy", "tile_occupancy_planes", "compact_tiles",
-           "compact_artifacts", "occupancy_stats"]
+           "compact_artifacts", "occupancy_stats", "tile_stats"]
 
 
 def tile_occupancy(a_packed_plane: jax.Array, tile_m: int, tile_w: int) -> jax.Array:
@@ -88,12 +88,16 @@ def compact_artifacts(a_packed: jax.Array, tile_m: int, tile_w: int):
 
 
 def occupancy_stats(occ: jax.Array) -> dict:
-    total = occ.size
-    nz = int(jnp.sum(occ))
+    return tile_stats(int(occ.size), int(jnp.sum(occ)))
+
+
+def tile_stats(total: int, nonzero: int) -> dict:
+    """Zero-tile accounting of a map of ``total`` tiles, ``nonzero``
+    occupied (host ints, no device sync)."""
     return {
-        "tiles_total": int(total),
-        "tiles_nonzero": nz,
-        "tiles_zero": int(total - nz),
-        "nonzero_ratio": nz / max(total, 1),
-        "skip_ratio": 1.0 - nz / max(total, 1),
+        "tiles_total": total,
+        "tiles_nonzero": nonzero,
+        "tiles_zero": total - nonzero,
+        "nonzero_ratio": nonzero / max(total, 1),
+        "skip_ratio": 1.0 - nonzero / max(total, 1),
     }

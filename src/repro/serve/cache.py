@@ -29,6 +29,15 @@ batch arrays are donated down that chain; the members' cached arrays never
 are. The compiled set is bounded by buckets x aligned member sizes x SGT
 presence, never by offsets or member order (:func:`compose_compiles`).
 
+A miss costs one compiled program per missed member
+(:func:`build_entries`): it slices the member out of the batch adjacency
+already on the device, at a traced offset, and builds every artifact of its
+entry there, whatever the jump policy, so an entry serves any policy that
+later consumes it. The host ints an entry carries (non-zero tiles,
+``s_max``, ``sgt_w``) come back for all the members of a step in one fetch,
+after every program is dispatched. The compiled set is bounded by buckets x
+aligned member sizes (:func:`build_compiles`).
+
 TC-GNN (PAPERS.md) motivates the same tile-occupancy-centric view of
 sparse adjacencies; here the occupancy map IS the cached object.
 """
@@ -42,7 +51,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["TileEntry", "TileCache", "compose_entries", "compose_compiles"]
+from repro.core import bitops
+from repro.core.zerotile import compact_tiles, tile_occupancy, tile_stats
+from repro.kernels import sgt
+
+__all__ = ["TileEntry", "TileCache", "build_entries", "build_compiles",
+           "compose_entries", "compose_compiles"]
 
 
 @dataclasses.dataclass
@@ -55,7 +69,7 @@ class TileEntry:
     occupancy: jax.Array   # (Mt/tm, Wt/tw) int32 0/1 tile-occupancy map
     compact_idx: jax.Array  # (Mt/tm, max_nnz) int32 non-zero k-tile ids
     compact_counts: jax.Array  # (Mt/tm,) int32
-    occ_stats: dict        # occupancy_stats() snapshot (host ints)
+    occ_stats: dict        # tile_stats() snapshot (host ints)
     s_max: int = 0         # host int: max(compact_counts) — sizes the
     #                        compact kernel's K grid without a device sync
     # sparse-graph translation artifacts (kernels/sgt.py): the per-row-
@@ -74,6 +88,24 @@ class TileEntry:
             if f is not None:
                 n += f.size * f.dtype.itemsize
         return n
+
+
+def _member_arrays(adj, off, n_sub: int, tm: int, tw: int):
+    """The entry arrays of the member at node offset ``off`` of the batch
+    adjacency, and its host-bound int32 stats [tiles_nonzero, s_max,
+    sgt_w]."""
+    sub = jax.lax.dynamic_slice(adj, (off, off), (n_sub, n_sub))
+    deg = jnp.sum(sub, axis=1, keepdims=True).astype(jnp.float32)
+    inv_deg = 1.0 / (deg + 1.0)
+    ap = bitops.pack_a(sub, 1)[0]
+    ap = bitops.pad_to(bitops.pad_to(ap, 0, tm), 1, tw)
+    occ = tile_occupancy(ap, tm, tw)
+    idx, counts = compact_tiles(occ)
+    # the SGT word-column remap rides along: same OR-reduction source,
+    # word granularity (sgt.word_occupancy reuses the packed plane)
+    s_idx, s_counts = compact_tiles(sgt.word_occupancy(ap, tm))
+    stats = jnp.stack([jnp.sum(occ), jnp.max(counts), jnp.max(s_counts)])
+    return (sub, inv_deg, ap, occ, idx, counts, s_idx, s_counts), stats
 
 
 def _empty_batch(n_pad: int, tm: int, tw: int, adj_dtype, have_sgt: bool,
@@ -119,6 +151,8 @@ def _place_member(batch, member, off, tm: int, tw: int):
     return adj, inv_deg, a_packed, occ, idx, counts, sgt
 
 
+# The build runs where the batch adjacency lives; the offset is traced.
+_build = jax.jit(_member_arrays, static_argnums=(2, 3, 4))
 # The init program has no array input, so its device is a static argument;
 # placement runs where its inputs live. Only the batch arrays are donated.
 _init = jax.jit(_empty_batch, static_argnums=(0, 1, 2, 3, 4, 5))
@@ -131,6 +165,41 @@ def _offset_on(off: int, device) -> jax.Array:
     offsets are few (multiples of the alignment below the top bucket), and
     a fresh host scalar per placement would cost a transfer each time."""
     return jax.device_put(np.int32(off), device)
+
+
+def build_compiles() -> int:
+    """Compiled build variants, shared by every caller in the process."""
+    return _build._cache_size()
+
+
+def build_entries(adj: jax.Array, members: list[tuple[int, int]],
+                  block_m: int, block_w: int) -> list[TileEntry]:
+    """Tile entries of the members ``(offset, n_sub)`` of the batch
+    adjacency ``adj``, each the ``(n_sub, n_sub)`` block at
+    ``(offset, offset)``.
+
+    Dispatches one compiled program per member, then reads every member's
+    host ints in one fetch (module docstring); the entries live on
+    ``adj``'s device.
+    """
+    n = adj.shape[0]
+    for off, n_sub in members:
+        if off < 0 or off + n_sub > n:
+            raise ValueError(f"member at offset {off} (size {n_sub}) does "
+                             f"not fit the {n}-node batch adjacency")
+    device, = adj.devices()
+    built = [_build(adj, _offset_on(off, device), n_sub, block_m, block_w)
+             for off, n_sub in members]
+    stats = jax.device_get([st for _, st in built])
+    entries = []
+    for (arrays, _), (nonzero, s_max, sgt_w) in zip(built, stats):
+        sub, inv_deg, ap, occ, idx, counts, s_idx, s_counts = arrays
+        entries.append(TileEntry(
+            adj=sub, inv_deg=inv_deg, a_packed=ap, occupancy=occ,
+            compact_idx=idx, compact_counts=counts,
+            occ_stats=tile_stats(occ.size, int(nonzero)), s_max=int(s_max),
+            sgt_idx=s_idx, sgt_counts=s_counts, sgt_w=int(sgt_w)))
+    return entries
 
 
 def compose_compiles() -> int:
@@ -182,16 +251,9 @@ def compose_entries(entries: list[TileEntry], offsets: list[int],
         batch = _place(batch, member, _offset_on(off, device), tm, tw)
     adj, inv_deg, a_packed, occ, idx, counts, sgt = batch
     sgt_idx, sgt_counts = sgt or (None, None)
-    mt, kt = n_pad // tm, n_pad // step
-    tiles_nonzero = sum(e.occ_stats["tiles_nonzero"] for e in entries)
-    total = mt * kt
-    occ_stats = {
-        "tiles_total": total,
-        "tiles_nonzero": tiles_nonzero,
-        "tiles_zero": total - tiles_nonzero,
-        "nonzero_ratio": tiles_nonzero / max(total, 1),
-        "skip_ratio": 1.0 - tiles_nonzero / max(total, 1),
-    }
+    occ_stats = tile_stats(
+        (n_pad // tm) * (n_pad // step),
+        sum(e.occ_stats["tiles_nonzero"] for e in entries))
     return TileEntry(adj=adj, inv_deg=inv_deg, a_packed=a_packed,
                      occupancy=occ, compact_idx=idx, compact_counts=counts,
                      occ_stats=occ_stats,

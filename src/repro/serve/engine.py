@@ -61,15 +61,14 @@ import numpy as np
 from repro import api
 from repro.core import bitops
 from repro.core.quantize import QuantParams
-from repro.core.zerotile import compact_tiles, occupancy_stats, tile_occupancy
 from repro.dist.elastic import StragglerWatchdog, replan_mesh
-from repro.kernels import sgt
 from repro.graph.batching import SubgraphBatch
 from repro.graph.packing import (compound_nbytes, transfer_packed,
                                  transfer_packed_feats)
 from repro.models import gnn
 from repro.perf import report, spans
-from repro.serve.cache import (TileCache, TileEntry, compose_compiles,
+from repro.serve.cache import (TileCache, TileEntry, build_compiles,
+                               build_entries, compose_compiles,
                                compose_entries)
 from repro.serve.chaos import ReplicaFault
 from repro.serve.queue import (AdmissionPolicy, CoalescedBatch, MicroBatcher,
@@ -405,6 +404,12 @@ class GNNServer:
         return compose_compiles()
 
     @property
+    def n_build_compiles(self) -> int:
+        """Compiled tile-entry build programs (one per bucket, aligned
+        member size and device); shared by every server in the process."""
+        return build_compiles()
+
+    @property
     def align(self) -> int:
         """Node alignment of the composition grid (the policy's tile
         footprint). A ``node_budget`` equal to this forces single-request
@@ -691,24 +696,8 @@ class GNNServer:
         return self._dev_params[device]
 
     def _build_entry(self, adj) -> TileEntry:
-        deg = jnp.sum(adj, axis=1, keepdims=True).astype(jnp.float32)
-        inv_deg = 1.0 / (deg + 1.0)
-        tm, tw = self._tile_shape
-        ap = bitops.pack_a(adj, 1)[0]
-        ap = bitops.pad_to(bitops.pad_to(ap, 0, tm), 1, tw)
-        occ = tile_occupancy(ap, tm, tw)
-        idx, counts = compact_tiles(occ)
-        # the SGT word-column remap rides along: same OR-reduction source,
-        # word granularity (sgt.word_occupancy reuses the packed plane)
-        wocc = sgt.word_occupancy(ap, tm)
-        s_idx, s_counts = compact_tiles(wocc)
-        return TileEntry(adj=adj, inv_deg=inv_deg, a_packed=ap,
-                         occupancy=occ, compact_idx=idx,
-                         compact_counts=counts,
-                         occ_stats=occupancy_stats(occ),
-                         s_max=int(jnp.max(counts)),
-                         sgt_idx=s_idx, sgt_counts=s_counts,
-                         sgt_w=int(jnp.max(s_counts)))
+        """The tile entry of a whole adjacency (one build program)."""
+        return build_entries(adj, [(0, adj.shape[0])], *self._tile_shape)[0]
 
     def _policy_for_n(self, n_pad: int) -> api.ExecutionPolicy | None:
         """Per-bucket policy: constructor ``policy=`` > tuning table >
@@ -814,7 +803,8 @@ class GNNServer:
         order. With every member cached the batch ships features only; a
         partial or full miss ships the compound buffer, and the missing
         members' artifacts are built from aligned slices of the (already
-        device-resident) batch adjacency — one transfer either way.
+        device-resident) batch adjacency — one transfer either way, one
+        build program per missed member and one host fetch for all of them.
         """
         batch = plan.batch
         if self.cache is None:
@@ -842,16 +832,15 @@ class GNNServer:
             self.stats.cache_misses += 1
             if n_cached:
                 self.stats.cache_partial_hits += 1
-            with spans.span("serve.tile_build"):
-                for i, (e, key) in enumerate(zip(entries, keys)):
-                    if e is not None:
-                        continue
-                    off = offsets[i]
-                    n_sub = _ceil_to(plan.spans[i][2], self._align)
-                    sub_adj = jax.lax.dynamic_slice(adj, (off, off),
-                                                    (n_sub, n_sub))
-                    entries[i] = self._build_entry(sub_adj)
-                    self.cache.put(key, entries[i])
+            with spans.span("serve.tile_build") as sp:
+                missed = [i for i, e in enumerate(entries) if e is None]
+                built = build_entries(
+                    adj, [(offsets[i], _ceil_to(plan.spans[i][2], self._align))
+                          for i in missed], *self._tile_shape)
+                sp["programs"] = len(missed)
+                for i, e in zip(missed, built):
+                    entries[i] = e
+                    self.cache.put(keys[i], e)
         with spans.span("serve.compose") as sp:
             entry = self._composed.get(l2_key)
             sp["composed_hit"] = int(entry is not None)

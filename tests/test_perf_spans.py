@@ -165,8 +165,10 @@ def test_a_batch_with_misses_records_the_tile_build(served):
     misses = srv.cache.misses
     _, phases = _one_step(srv, reqs[2:4])  # that one and an unseen one
     assert sorted(phases) == sorted(PHASES + ("serve.tile_build",))
-    assert len(phases["serve.tile_build"]) == 1
+    build, = phases["serve.tile_build"]
     assert srv.cache.misses == misses + 1  # the one member it built
+    # one build program for the one missed member
+    assert build.attrs == {"programs": 1}
     compose, = phases["serve.compose"]
     # a miss: the init program, then one placement per member
     assert compose.attrs == {"composed_hit": 0, "programs": 3}

@@ -241,10 +241,12 @@ def test_serve_compact_tiles_consumed_and_bit_identical(setup, monkeypatch):
 def test_serve_sgt_tiles_consumed_and_bit_identical(setup, monkeypatch):
     """Under ``jump="sgt"`` the jitted forward consumes the cached
     word-column remap (TileEntry.sgt_idx/sgt_counts): logits bit-identical
-    to dense, the translation built ONCE per subgraph (at entry build, not
-    per call), and resident-bytes accounting flows into ServeStats."""
+    to dense, the translation built ONCE per subgraph (by the build program
+    on the miss, not per call), and resident-bytes accounting flows into
+    ServeStats."""
     from repro import api
     from repro.kernels import sgt
+    from repro.serve import cache
 
     data, parts, cfg, qparams = setup
     b = batching.make_batches(data, parts, 2, shuffle=False)[0]
@@ -252,22 +254,36 @@ def test_serve_sgt_tiles_consumed_and_bit_identical(setup, monkeypatch):
     dense = GNNServer(qparams, cfg, backend="pallas")
     _, lg_dense = dense.infer_batch(b, return_logits=True)
 
-    calls = {"n": 0}
-    orig = sgt.word_occupancy
+    # the build program is shared by the process (whether it traces here
+    # depends on what ran before), so count its dispatches, and count the
+    # translations traced anywhere else, i.e. inside the forward
+    calls = {"builds": 0, "elsewhere": 0, "in_build": False}
+    build, translate = cache._build, sgt.word_occupancy
 
-    def counting(*a, **kw):
-        calls["n"] += 1
-        return orig(*a, **kw)
+    def counting_build(*a, **kw):
+        calls["builds"] += 1
+        calls["in_build"] = True
+        try:
+            return build(*a, **kw)
+        finally:
+            calls["in_build"] = False
 
-    monkeypatch.setattr(sgt, "word_occupancy", counting)
+    def counting_translate(*a, **kw):
+        calls["elsewhere"] += not calls["in_build"]
+        return translate(*a, **kw)
+
+    monkeypatch.setattr(cache, "_build", counting_build)
+    monkeypatch.setattr(sgt, "word_occupancy", counting_translate)
     pol = api.ExecutionPolicy(jump="sgt")
     srv = GNNServer(qparams, cfg, backend="pallas", policy=pol)
     _, lg1 = srv.infer_batch(b, return_logits=True)   # miss: builds entry
+    assert calls["builds"] == 1
     _, lg2 = srv.infer_batch(b, return_logits=True)   # hit: cached remap
     assert srv.cache.misses == 1 and srv.cache.hits == 1
-    # exactly one translation: _build_entry on the miss; the jitted
-    # forward consumed the artifacts, never re-deriving them in-call
-    assert calls["n"] == 1
+    # exactly one translation: the build program on the miss, none on the
+    # hit; the jitted forward consumed the artifacts, never re-deriving
+    # them in-call
+    assert calls["builds"] == 1 and calls["elsewhere"] == 0
     np.testing.assert_array_equal(lg1, lg2)
     np.testing.assert_array_equal(lg1, lg_dense)
     entry = next(iter(srv.cache._entries.values()))
