@@ -103,22 +103,17 @@ def main(scale: float = 0.01, parts_k: int = 12, rounds: int = 4):
 
 def jump_arm(scale: float = 0.006, parts_k: int = 8,
              rounds: int = 3) -> list[dict]:
-    """Zero-tile DMA jumping on the serving path: dense vs compact vs
-    autotuned.
+    """Zero-tile DMA jumping on the serving path: dense vs compact.
 
     The single jump-mode serving runner — ``benchmarks/run.py`` collects
     its returned records into ``BENCH_kernels.json`` (via
-    ``kernel_bench``). All arms run the pallas backend; the two
-    hand-picked arms pin ``jump="none"`` / ``jump="compact"`` and the
-    ``autotuned`` arm passes NO policy, so the engine resolves each shape
-    bucket from the committed tuning table
-    (src/repro/tune/tables/cpu_kernels.json — see docs/tuning.md).
-    Logits are asserted bit-identical across all arms, the compact arm
-    must hold the dense arm's nodes/s, and the autotuned arm must hold
-    the BEST hand-picked arm's (both at a 10% wall-clock noise margin —
-    the windows are timed on a shared CPU). The warm-up wave (compiles +
-    tile-cache misses) is excluded from BOTH the throughput window and
-    the recorded latency percentiles.
+    ``kernel_bench``). Both arms run the pallas backend and pin
+    ``jump="none"`` / ``jump="compact"``. Logits are asserted
+    bit-identical across the arms, and the compact arm must hold the
+    dense arm's nodes/s (at a 10% wall-clock noise margin — the windows
+    are timed on a shared CPU). The warm-up wave (compiles + tile-cache
+    misses) is excluded from BOTH the throughput window and the recorded
+    latency percentiles.
     """
     key = jax.random.PRNGKey(0)
     name = "ogbn-arxiv"
@@ -130,17 +125,11 @@ def jump_arm(scale: float = 0.006, parts_k: int = 8,
     buckets = buckets_for(reqs, levels=2)
     parity_batch = batching.make_batches(data, parts, 2, shuffle=False)[0]
 
-    arms = {
-        "none": dict(policy=api.ExecutionPolicy(jump="none"),
-                     tuning_table=None),
-        "compact": dict(policy=api.ExecutionPolicy(jump="compact"),
-                        tuning_table=None),
-        "autotuned": dict(policy=None),  # tuning_table="auto" (committed)
-    }
     records, results = [], {}
-    for arm, kw in arms.items():
+    for arm in ("none", "compact"):
+        pol = api.ExecutionPolicy(jump=arm)
         srv = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                        **kw)
+                        policy=pol)
         _, logits = srv.infer_batch(parity_batch, return_logits=True)
         for r in reqs:  # warm-up wave: compiles + tile-cache misses
             srv.submit(SubgraphRequest(edges=r.edges, features=r.features,
@@ -157,46 +146,26 @@ def jump_arm(scale: float = 0.006, parts_k: int = 8,
         dt = time.perf_counter() - t0
         nps = (srv.stats.nodes - n0) / dt
         results[arm] = (nps, logits)
-        # the jump mode an autotuned server actually ran: its largest
-        # bucket's table policy (None = no table entry -> default dense)
-        pol = kw.get("policy")
-        if pol is None:
-            tuned = [p for p in srv.tuned_policies().values()
-                     if p is not None]
-            jump = tuned[-1]["jump"] if tuned else "none"
-        else:
-            jump = pol.jump
         records.append({
             "op": "serve_forward", "bits": srv.feat_bits,
             "sparsity": round(srv.stats.zero_tile_skip_ratio, 4),
-            "jump": jump, "median_ms": round(srv.stats.p50_s * 1e3, 3),
+            "jump": arm, "median_ms": round(srv.stats.p50_s * 1e3, 3),
             "nodes_per_s": round(nps, 1), "arm": arm,
         })
         emit(f"serve_{name}_pallas_jump_{arm}", round(nps, 1), "nodes_per_s",
              wall_s=round(dt, 3), p50_ms=records[-1]["median_ms"],
              skip_ratio=round(srv.stats.zero_tile_skip_ratio, 4),
-             cache_hit_rate=round(srv.cache.hit_rate, 3), jump=jump)
+             cache_hit_rate=round(srv.cache.hit_rate, 3), jump=arm)
     nps_dense, lg_dense = results["none"]
     nps_jump, lg_jump = results["compact"]
-    nps_auto, lg_auto = results["autotuned"]
     emit(f"serve_{name}_jump_speedup", round(nps_jump / nps_dense, 2), "x",
          derived=True)
     np.testing.assert_array_equal(
         np.asarray(lg_jump), np.asarray(lg_dense),
         err_msg="compact-jump serving logits diverged from dense")
-    np.testing.assert_array_equal(
-        np.asarray(lg_auto), np.asarray(lg_dense),
-        err_msg="autotuned serving logits diverged from dense")
     assert nps_jump >= 0.9 * nps_dense, (
         f"compact-jump arm ({nps_jump:.1f} nodes/s) fell below the dense "
         f"arm ({nps_dense:.1f} nodes/s) beyond wall-clock noise")
-    best_hand = max(nps_dense, nps_jump)
-    emit(f"serve_{name}_autotuned_vs_best", round(nps_auto / best_hand, 2),
-         "x", derived=True)
-    assert nps_auto >= 0.9 * best_hand, (
-        f"autotuned arm ({nps_auto:.1f} nodes/s) fell below the best "
-        f"hand-picked arm ({best_hand:.1f} nodes/s) beyond wall-clock "
-        f"noise — the committed tuning table is mistuned for this host")
     return records
 
 
@@ -217,7 +186,7 @@ def sgt_arm(scale: float = 0.006, parts_k: int = 8,
     cfg, qparams, reqs, buckets = _setup(name, scale, parts_k)
     pol = api.ExecutionPolicy(jump="sgt")
     srv = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                    policy=pol, tuning_table=None)
+                    policy=pol)
     for r in reqs:  # warm-up wave: compiles + tile-cache misses
         srv.submit(_fresh(r))
     srv.drain()
@@ -233,7 +202,7 @@ def sgt_arm(scale: float = 0.006, parts_k: int = 8,
     for tag, kw in (("scratch", dict(policy=pol, cache_entries=0)),
                     ("dense", dict(policy=api.ExecutionPolicy(jump="none")))):
         ref = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                        tuning_table=None, **kw)
+                        **kw)
         rids = [ref.submit(_fresh(r)) for r in reqs]
         rout = ref.drain(return_logits=True)
         for got, rid in zip(logits, rids):

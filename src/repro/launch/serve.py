@@ -132,10 +132,6 @@ def serve_gnn(args) -> dict:
                                     max_nodes=args.max_queued_nodes,
                                     max_edges=args.max_queued_edges,
                                     on_full=args.admission)
-    # policy source: "auto" = the active repro.tune table (committed
-    # artifact by default), "off" = hand-picked defaults, PATH = a table
-    # emitted by `python -m repro.launch.sweep`
-    table = (None if args.tuning_table == "off" else args.tuning_table)
     # deterministic chaos: --inject-failure specs go through the ONE
     # sanctioned fault source (serve/chaos.py), mirroring
     # launch.train --simulate-failure-at
@@ -149,7 +145,7 @@ def serve_gnn(args) -> dict:
     with mesh, shd.shard_ctx(mesh, shd.make_rules("serve")):
         server = GNNServer(qparams, cfg, feat_bits=args.feat_bits,
                            buckets=buckets, mesh=mesh, admission=admission,
-                           cache_bytes=args.cache_bytes, tuning_table=table,
+                           cache_bytes=args.cache_bytes,
                            replicas=args.replicas, chaos=chaos,
                            straggler_tolerance=args.straggler_tolerance)
         for rnd in range(args.rounds):
@@ -166,7 +162,6 @@ def serve_gnn(args) -> dict:
     summary = server.stats.summary()
     summary["model"] = cfg.model
     summary["n_compiles"] = server.n_compiles
-    summary["tuned_policies"] = server.tuned_policies()
     summary["replicas"] = server.stats.replicas_live
     if chaos is not None:
         summary["chaos_fired"] = chaos.fired
@@ -234,11 +229,6 @@ def main(argv=None) -> dict:
                     help="evict a replica whose batch wall time exceeds "
                          "TOL x its rolling p50 for consecutive batches "
                          "(default: detection off)")
-    ap.add_argument("--tuning-table", default="auto", metavar="PATH",
-                    help="GNN execution-policy source: 'auto' (active "
-                         "repro.tune table, the default), 'off' "
-                         "(hand-picked defaults), or a table file from "
-                         "python -m repro.launch.sweep")
     args = ap.parse_args(argv)
     if args.gnn is None and (args.arch is None or args.arch in GNN_CONFIGS):
         ap.error("pass --arch (LM), or --gnn DATASET with an optional "

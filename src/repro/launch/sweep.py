@@ -7,11 +7,10 @@
 
 ``--smoke`` runs the built-in tiny grid (CI's sweep-smoke job); otherwise
 ``--config`` names a JSON sweep config (format: docs/tuning.md). The
-emitted table is what `repro.api` dispatch and `GNNServer` consult when
-no explicit policy is given — write it to the packaged default path
+emitted table is what `repro.api` kernel dispatch consults when no
+explicit policy is given — write it to the packaged default path
 (src/repro/tune/tables/cpu_kernels.json) to make it the committed
-artifact, or point consumers at it explicitly
-(``repro.launch.serve --tuning-table PATH``, ``repro.tune.install``).
+artifact, or install it explicitly (``repro.tune.install``).
 
 ``--bench-out`` merges the sweep's trajectory records into a
 BENCH_kernels.json-style file: previous ``phase == "sweep"`` records are

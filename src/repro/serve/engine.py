@@ -75,7 +75,6 @@ from repro.serve.queue import (AdmissionPolicy, CoalescedBatch, MicroBatcher,
                                SubgraphRequest, _ceil_to,
                                subgraph_fingerprint)
 from repro.serve.router import ReplicaRouter
-from repro.tune import table as tune_table
 
 __all__ = ["GNNServer", "ServeStats", "STATS_WINDOW"]
 
@@ -234,16 +233,10 @@ class GNNServer:
     consecutive batches is removed from the routing set (its traffic
     re-homes; None = detection off).
 
-    ``tuning_table`` feeds the policy fallback chain when ``policy=None``:
-    each shape bucket resolves its own tuned ``serve_forward`` policy at
-    jit time (one nearest-bucket lookup per ``n_pad``, memoized — the jit
-    cache stays bounded at one executable per bucket). ``"auto"`` (the
-    default) snapshots the active table from ``repro.tune`` at
-    construction (``use_table`` context > ``install()`` > the committed
-    artifact); pass a path or TuningTable to pin one, or None to disable
-    tuning. An explicit ``policy=`` always wins, and an unusable table
-    file warns and degrades to the ambient policy — it never fails
-    construction.
+    The tile grid (``block_m``/``block_w``) comes from ``policy=`` or,
+    when it is None, from the ambient ``repro.api`` policy at
+    construction; nothing else decides it. The tile cache is the only
+    device state that grows with traffic.
     """
 
     def __init__(self, qparams: dict, cfg: gnn.GNNConfig, feat_bits: int = 8,
@@ -252,7 +245,7 @@ class GNNServer:
                  edge_budget: int | None = None, tile: int = 128,
                  cache_entries: int = 64, cache_bytes: int | None = None,
                  mesh=None, admission: AdmissionPolicy | None = None,
-                 tuning_table="auto", replicas: int | None = None,
+                 replicas: int | None = None,
                  chaos=None, max_retries: int = 3,
                  straggler_tolerance: float | None = None,
                  straggler_strikes: int = 2):
@@ -260,38 +253,13 @@ class GNNServer:
         self.cfg = cfg
         self.feat_bits = feat_bits
         self.backend = backend
-        self.policy = policy  # None = table entry, else the active context
-        if tuning_table == "auto":
-            self._table = tune_table.active_table()
-        elif tuning_table is None or isinstance(tuning_table,
-                                                tune_table.TuningTable):
-            self._table = tuning_table
-        else:  # a path: corrupt/stale/missing warns and disables tuning
-            self._table = tune_table.TuningTable.load(tuning_table)
-        self._bucket_pols: dict = {}  # n_pad -> tuned policy | None
+        self.policy = policy  # None = the active context at trace time
         self.stats = ServeStats()
         self.cache = (TileCache(cache_entries, cache_bytes=cache_bytes)
                       if cache_entries > 0 else None)
         # block offsets aligned to the kernel tile footprint so cached
         # per-subgraph artifacts compose into any batch by offset shifting.
-        # With no explicit policy the table's largest-bucket entry sets the
-        # footprint — but only when its grid divides the batcher tile and
-        # every bucket (a tuned grid must not invalidate the ladder the
-        # caller already built); otherwise the ambient policy's grid holds.
-        pol0 = policy
-        if pol0 is None and self._table is not None:
-            probe = max((b.n_pad for b in (buckets or ())), default=tile)
-            cand = self._table.policy_for(
-                "serve_forward", bits=feat_bits,
-                shape=(probe, probe, cfg.in_dim))
-            if cand is not None:
-                align = math.lcm(cand.block_m, 32 * cand.block_w)
-                if (tile % align == 0
-                        and not any(b.n_pad % align
-                                    for b in (buckets or ()))):
-                    pol0 = cand
-        if pol0 is None:
-            pol0 = api.current()[1]
+        pol0 = policy if policy is not None else api.current()[1]
         self._align = math.lcm(pol0.block_m, 32 * pol0.block_w)
         self._tile_shape = (pol0.block_m, pol0.block_w)
         # fail fast: every batch shape the batcher can produce must land
@@ -313,16 +281,6 @@ class GNNServer:
                                     edge_budget=edge_budget, tile=tile,
                                     align=self._align, admission=admission)
         self._spill: dict = {}  # results produced by block-mode submits
-        # L2: composed batch entries memoized by (ordered member
-        # fingerprints, n_pad, device). Pure memoization — a composed
-        # entry is a deterministic function of its key, so it never needs
-        # invalidation, only LRU bounding. A REPEATED coalescing order
-        # skips the per-batch composition entirely (the old per-group
-        # fast path); a novel order composes once from the per-subgraph
-        # L1 entries and is memoized for next time.
-        self._composed: collections.OrderedDict = collections.OrderedDict()
-        self._composed_cap = cache_entries  # same envelope as the old
-        #                                     per-group cache it replaces
         self._devices = (list(mesh.devices.flat) if mesh is not None
                          else [None])
         self._mesh = mesh
@@ -354,10 +312,8 @@ class GNNServer:
         # one executable per input-shape set, i.e. per (bucket, device) —
         # plus, when cached compact tiles are consumed, per power-of-two
         # rounded non-zero-tile count (s_max is static: it sizes the
-        # compact kernel's K grid). ``pol`` is the per-bucket policy
-        # resolved by _policy_for_n — static, so each bucket compiles with
-        # its tuned policy; None means "resolve the ambient context at
-        # trace time" (the pre-table behavior).
+        # compact kernel's K grid). ``pol`` is the constructor policy
+        # (static); None means "resolve the ambient context at trace time".
         d_in = cfg.in_dim
         fbits = feat_bits
         be = backend
@@ -610,9 +566,6 @@ class GNNServer:
             self.stats.cache_rehomed_entries += n
             self.stats.cache_rehomed_bytes += nbytes
             self.stats.cache_resident_bytes = self.cache.resident_bytes
-        for k in [k for k in self._composed
-                  if isinstance(k, tuple) and k[-1] == replica]:
-            del self._composed[k]
         self._watchdogs.pop(replica, None)
         self._strikes.pop(replica, None)
         self._replica_dev.pop(replica, None)
@@ -699,30 +652,6 @@ class GNNServer:
         """The tile entry of a whole adjacency (one build program)."""
         return build_entries(adj, [(0, adj.shape[0])], *self._tile_shape)[0]
 
-    def _policy_for_n(self, n_pad: int) -> api.ExecutionPolicy | None:
-        """Per-bucket policy: constructor ``policy=`` > tuning table >
-        None (= resolve the ambient context per call, pre-table behavior).
-
-        Table lookups are memoized per ``n_pad`` — deterministic per
-        bucket, so the jitted forward still compiles once per bucket
-        (``n_compiles`` ≤ buckets holds with tuning on).
-        """
-        if self.policy is not None:
-            return self.policy
-        if self._table is None:
-            return None
-        if n_pad not in self._bucket_pols:
-            self._bucket_pols[n_pad] = self._table.policy_for(
-                "serve_forward", bits=self.feat_bits,
-                shape=(n_pad, n_pad, self.cfg.in_dim))
-        return self._bucket_pols[n_pad]
-
-    def tuned_policies(self) -> dict:
-        """{n_pad: policy-field dict | None} resolved so far (probes/CLI)."""
-        from repro.tune.table import policy_to_dict
-        return {n: (policy_to_dict(p) if p is not None else None)
-                for n, p in sorted(self._bucket_pols.items())}
-
     def _jump_tiles(self, entry: TileEntry, pol=None):
         """Cached jump artifacts for the jitted forward: (idx, counts,
         s_max, kind) with kind "compact" | "sgt" | None (no artifacts).
@@ -730,8 +659,7 @@ class GNNServer:
         Active when the engine's (backend, policy) pair asks for compact
         jumping or sparse-graph translation and the backend can exploit
         it. ``pol=None`` resolves the constructor policy or the ambient
-        context (the per-bucket tuned policy is passed in by
-        ``_forward``). ``s_max`` is rounded up to the next power of two
+        context. ``s_max`` is rounded up to the next power of two
         (clamped to the grid bound) so the jit cache stays small: one
         executable per (bucket, rounded count), not one per distinct
         subgraph sparsity.
@@ -818,8 +746,6 @@ class GNNServer:
         entries = [self.cache.get(k) for k in keys]
         n_cached = sum(e is not None for e in entries)
         offsets = [off for _, off, _ in plan.spans]
-        l2_key = (tuple(r.fingerprint for r in plan.requests),
-                  batch.n_nodes, rep)
         if n_cached == len(entries):
             packed, meta = transfer_packed_feats(batch, nbits=self.feat_bits,
                                                  device=device)
@@ -841,19 +767,9 @@ class GNNServer:
                 for i, e in zip(missed, built):
                     entries[i] = e
                     self.cache.put(keys[i], e)
-        with spans.span("serve.compose") as sp:
-            entry = self._composed.get(l2_key)
-            sp["composed_hit"] = int(entry is not None)
-            sp["programs"] = 0 if entry is not None else 1 + len(entries)
-            if entry is None:
-                tm, tw = self._tile_shape
-                entry = compose_entries(entries, offsets, batch.n_nodes,
-                                        tm, tw)
-                self._composed[l2_key] = entry
-                while len(self._composed) > self._composed_cap:
-                    self._composed.popitem(last=False)
-            else:
-                self._composed.move_to_end(l2_key)
+        with spans.span("serve.compose"):
+            entry = compose_entries(entries, offsets, batch.n_nodes,
+                                    *self._tile_shape)
         return self._forward(device, entry, packed, meta), entry
 
     def _forward(self, device, entry: TileEntry, packed, meta):
@@ -865,11 +781,10 @@ class GNNServer:
                                                  meta))
 
     def _forward_args(self, device, entry: TileEntry, packed, meta) -> tuple:
-        pol = self._policy_for_n(entry.adj.shape[0])
-        t_idx, t_cnt, s_max, t_kind = self._jump_tiles(entry, pol)
+        t_idx, t_cnt, s_max, t_kind = self._jump_tiles(entry)
         return (self._params_for(device), entry.adj, packed,
                 jnp.float32(meta["scale"]), jnp.float32(meta["zero"]),
-                entry.inv_deg, t_idx, t_cnt, s_max, t_kind, pol)
+                entry.inv_deg, t_idx, t_cnt, s_max, t_kind, self.policy)
 
     def lowered(self, batch: SubgraphBatch):
         """The jitted forward lowered, not run, for one batch under this

@@ -13,10 +13,6 @@ describing a measurement grid:
   candidates     — list of ExecutionPolicy field-override dicts applied
                    over DEFAULT_POLICY ({} = the hand-picked default arm)
   iters/warmup   — timing repeats (median) / warm-up runs per arm
-  serve          — optional serving section (dataset/scale/parts/rounds/
-                   feat_bits/levels/candidates): streams repeat subgraph
-                   traffic through GNNServer per candidate and emits one
-                   "serve_forward" table entry per shape bucket
 
 Every candidate is asserted bit-identical against the dense ``xla_dot``
 reference AS it is timed — a sweep doubles as a cross-backend exactness
@@ -35,9 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import inspect
-import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -45,7 +39,7 @@ from repro import api
 from repro.api.policy import DEFAULT_POLICY, ExecutionPolicy
 from repro.core import bitops, zerotile
 from repro.kernels import sgt as sgt_lib
-from repro.perf.report import bench_median, percentile
+from repro.perf.report import bench_median
 from repro.tune.table import (TableEntry, TuningTable, policy_to_dict,
                               provenance)
 
@@ -80,11 +74,6 @@ SMOKE_CONFIG = {
                    {"block_m": 12}],
     "iters": 2,
     "warmup": 1,
-    "serve": {
-        "dataset": "ogbn-arxiv", "scale": 0.004, "parts": 4,
-        "rounds": 1, "levels": 2,
-        "candidates": [{}, {"jump": "compact"}, {"jump": "sgt"}],
-    },
 }
 
 
@@ -221,105 +210,6 @@ def _sweep_cell(op, bits, band, shape, backend, cands, iters, warmup,
     return entry, records
 
 
-# ---------------------------------------------------------------- serve arm
-
-def _sweep_serve(scfg, rejected, log, source="config"):
-    """Stream repeat traffic through GNNServer per candidate; the winner
-    (by nodes/s, logits asserted bit-identical across candidates) becomes
-    one serve_forward entry per shape bucket.
-
-    Candidates must keep DEFAULT_POLICY's tile grid: the bucket ladder,
-    offset alignment and cache composition are all built on it — a
-    grid-changing candidate is rejected legibly, not silently mistuned.
-    """
-    from repro.graph import datasets, partition
-    from repro.models import gnn
-    from repro.serve import GNNServer, SubgraphRequest
-    from repro.serve.queue import buckets_for, requests_from_partitions
-
-    backend = scfg.get("backend", "pallas")
-    feat_bits = int(scfg.get("feat_bits", 8))
-    rounds = int(scfg.get("rounds", 1))
-    data = datasets.load(scfg.get("dataset", "ogbn-arxiv"),
-                         scale=float(scfg.get("scale", 0.004)))
-    parts = partition.partition(data.csr, int(scfg.get("parts", 4)))
-    cfg = gnn.GNNConfig.paper_gcn(data.features.shape[1], data.n_classes,
-                                  x_bits=feat_bits, w_bits=feat_bits)
-    qparams = gnn.quantize_params(
-        gnn.init_params(jax.random.PRNGKey(0), cfg), cfg)
-    reqs = requests_from_partitions(data, parts)
-    buckets = buckets_for(reqs, levels=int(scfg.get("levels", 2)))
-
-    default_grid = (DEFAULT_POLICY.block_m, DEFAULT_POLICY.block_n,
-                    DEFAULT_POLICY.block_w)
-    arms, records = [], []
-    ref_logits = None
-    for ov, pol, src in _candidates(scfg.get("candidates",
-                                             ({}, {"jump": "compact"})),
-                                    rejected, source=f"{source}:serve"):
-        if (pol.block_m, pol.block_n, pol.block_w) != default_grid:
-            rejected.append({
-                "candidate": dict(ov), "source": src,
-                "error": f"{src}: serve sweep candidates must keep the "
-                         f"default tile grid (the bucket ladder and cache "
-                         f"composition are built on it)"})
-            continue
-        srv = GNNServer(qparams, cfg, feat_bits=feat_bits, backend=backend,
-                        policy=pol, buckets=buckets, tuning_table=None)
-        for r in reqs:  # warm-up wave: compiles + tile-cache misses
-            srv.submit(SubgraphRequest(edges=r.edges, features=r.features,
-                                       n_nodes=r.n_nodes))
-        srv.drain()
-        srv.stats.batch_latencies_s.clear()
-        n0, t0 = srv.stats.nodes, time.perf_counter()
-        logits = []
-        for _ in range(rounds):
-            ids = [srv.submit(SubgraphRequest(edges=r.edges,
-                                              features=r.features,
-                                              n_nodes=r.n_nodes))
-                   for r in reqs]
-            out = srv.drain(return_logits=True)
-            logits = [out[i][1] for i in ids]
-        dt = time.perf_counter() - t0
-        nps = (srv.stats.nodes - n0) / dt
-        if ref_logits is None:
-            ref_logits = logits
-        else:  # tuning must never change answers — assert as we measure
-            for got, want in zip(logits, ref_logits):
-                np.testing.assert_array_equal(
-                    np.asarray(got), np.asarray(want),
-                    err_msg=f"serve sweep parity: candidate {ov}")
-        p50_ms = 1e3 * percentile(srv.stats.batch_latencies_s, 50)
-        skip = round(srv.stats.zero_tile_skip_ratio, 4)
-        rec = {
-            "op": "serve_forward", "bits": feat_bits, "sparsity": skip,
-            "jump": pol.jump, "median_ms": round(p50_ms, 3),
-            "nodes_per_s": round(nps, 1), "backend": backend,
-            "phase": "sweep", "candidate": dict(ov),
-            "policy": policy_to_dict(pol),
-        }
-        records.append(rec)
-        arms.append((nps, ov, pol, skip, p50_ms, rec))
-        log(f"[sweep] serve candidate {ov or 'default'}: "
-            f"{nps:.1f} nodes/s, p50 {p50_ms:.3f}ms, skip {skip}")
-    if not arms:
-        return [], records
-    nps, ov, pol, skip, p50_ms, rec = max(arms, key=lambda x: x[0])
-    rec["best"] = True
-    base_p50 = next((a[4] for a in arms if not a[1]), None)
-    entries = [TableEntry(op="serve_forward", bits=feat_bits,
-                          sparsity_band=skip,
-                          shape_bucket=(b.n_pad, b.n_pad, cfg.in_dim),
-                          policy=pol, backend=backend,
-                          median_ms=round(p50_ms, 3),
-                          baseline_ms=(round(base_p50, 3)
-                                       if base_p50 is not None else None))
-               for b in buckets]
-    log(f"[sweep] serve best={ov or 'default'} -> "
-        f"{len(entries)} bucket entries")
-    return entries, records
-
-
 # -------------------------------------------------------------------- driver
 
 def run_sweep(config: dict, *, log=print, source: str | None = None
@@ -352,11 +242,6 @@ def run_sweep(config: dict, *, log=print, source: str | None = None
                                   iters, warmup, rng, log)
         entries.append(entry)
         records.extend(recs)
-    if config.get("serve"):
-        serve_entries, serve_recs = _sweep_serve(config["serve"], rejected,
-                                                 log, source=source)
-        entries.extend(serve_entries)
-        records.extend(serve_recs)
     meta = provenance({
         "config": config.get("name", "unnamed"),
         "generated_by": "repro.launch.sweep",

@@ -168,9 +168,9 @@ def test_cpu_tuning_table_has_no_opinion_on_a_tpu(monkeypatch):
     table = tune_table.default_table()
     assert table is not None and table.meta["jax_backend"] == "cpu"
     query = dict(bits=8, shape=(512, 512, IN_DIM))
-    assert table.policy_for("serve_forward", **query) is not None
+    assert table.policy_for("bitserial_mm", **query) is not None
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert table.policy_for("serve_forward", **query) is None
+    assert table.policy_for("bitserial_mm", **query) is None
     assert tune_table.dispatch_policy("bitserial_mm", **query) is None
 
 

@@ -153,8 +153,8 @@ def test_a_cached_batch_records_each_phase_once(served):
     assert sorted(phases) == sorted(PHASES)
     assert all(len(v) == 1 for v in phases.values())
     compose, = phases["serve.compose"]
-    # the same order again: a memo hit dispatches no program
-    assert compose.attrs == {"composed_hit": 1, "programs": 0}
+    # the same order again composes anew; the span carries no attributes
+    assert compose.attrs == {}
     for (r,) in phases.values():
         assert step.t0 <= r.t0 <= r.t1 <= step.t1
 
@@ -170,5 +170,4 @@ def test_a_batch_with_misses_records_the_tile_build(served):
     # one build program for the one missed member
     assert build.attrs == {"programs": 1}
     compose, = phases["serve.compose"]
-    # a miss: the init program, then one placement per member
-    assert compose.attrs == {"composed_hit": 0, "programs": 3}
+    assert compose.attrs == {}

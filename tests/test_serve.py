@@ -304,6 +304,43 @@ def test_serve_sgt_tiles_consumed_and_bit_identical(setup, monkeypatch):
     assert plain._jump_tiles(entry) == (None, None, 0, None)
 
 
+@pytest.mark.parametrize("jump", ["none", "compact", "sgt"])
+def test_ambient_policy_serves_like_an_explicit_one(setup, jump):
+    """With no ``policy=`` the server takes the ambient ``repro.api``
+    policy: built and served inside ``api.use`` it consumes that policy's
+    cached tiles and gives the logits of a server handed the policy
+    explicitly, compiling at most once per bucket."""
+    from repro import api
+    from repro.serve.queue import buckets_for
+
+    data, parts, cfg, qparams = setup
+    reqs = _fresh_requests(data, parts)[:4]
+    buckets = buckets_for(reqs, levels=2)
+    pol = api.ExecutionPolicy(jump=jump)
+
+    def serve(srv):
+        ids = [srv.submit(SubgraphRequest(edges=r.edges, features=r.features,
+                                          n_nodes=r.n_nodes))
+               for r in reqs]
+        out = srv.drain(return_logits=True)
+        return [out[i][1] for i in ids]
+
+    explicit = GNNServer(qparams, cfg, backend="pallas", policy=pol,
+                         buckets=buckets)
+    want = serve(explicit)
+    with api.use("pallas", policy=pol):
+        ambient = GNNServer(qparams, cfg, buckets=buckets)
+        got = serve(ambient)
+        entry = next(iter(ambient.cache._entries.values()))
+        kind = ambient._jump_tiles(entry)[3]
+    assert ambient.policy is None
+    assert kind == (None if jump == "none" else jump)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    for srv in (explicit, ambient):
+        assert 0 < srv.n_compiles <= len(buckets)
+
+
 def test_compose_entries_sgt_matches_scratch(setup):
     """A coalesced batch's SGT remap composed from per-subgraph cached
     entries (word-offset shifting) is bit-identical to building the

@@ -280,14 +280,22 @@ def test_mismatched_ambient_grid_drops_cached_tiles(setup):
         assert srv._jump_tiles(entry)[0] is not None
 
 
-def test_misaligned_buckets_fail_at_construction(setup):
+@pytest.mark.parametrize("grid_from", ["policy", "ambient"])
+def test_misaligned_buckets_fail_at_construction(setup, grid_from):
+    """The tile grid comes from ``policy=`` or, without one, from the
+    ambient ``repro.api`` policy; either way a grid the bucket ladder is
+    not aligned to fails at construction."""
     cfg, qparams, reqs = setup
     from repro import api
 
     buckets = buckets_for(reqs, levels=2)
+    pol = api.ExecutionPolicy(block_w=8)
     with pytest.raises(ValueError, match="tile"):
-        GNNServer(qparams, cfg, policy=api.ExecutionPolicy(block_w=8),
-                  buckets=buckets)
+        if grid_from == "policy":
+            GNNServer(qparams, cfg, policy=pol, buckets=buckets)
+        else:
+            with api.use(policy=pol):
+                GNNServer(qparams, cfg, buckets=buckets)
 
 
 def test_routing_fingerprint_is_order_insensitive(setup):

@@ -3,6 +3,8 @@ with the whole-batch build, a compiled set bounded by shapes, donation
 that never touches a resident entry, and placement on the members'
 device."""
 import dataclasses
+import gc
+import itertools
 import os
 import subprocess
 import sys
@@ -15,6 +17,7 @@ import pytest
 from repro.models import gnn
 from repro.perf import spans
 from repro.serve import GNNServer, SubgraphRequest, compose_entries
+from repro.serve import engine
 from repro.serve.queue import make_buckets
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -116,7 +119,8 @@ def _unchanged(entries, snap):
             np.testing.assert_array_equal(np.asarray(getattr(e, f)), arr)
 
 
-def test_shuffled_orders_compile_nothing_and_donate_no_resident(server_args):
+def test_shuffled_orders_compile_nothing_and_donate_no_resident(
+        server_args, monkeypatch):
     rng = np.random.default_rng(7)
     small = [_request(rng, 100) for _ in range(4)]  # aligned to 128
     large = [_request(rng, 200) for _ in range(2)]  # aligned to 256
@@ -127,31 +131,55 @@ def test_shuffled_orders_compile_nothing_and_donate_no_resident(server_args):
     buckets = make_buckets(node_budget=512, edge_budget=4096, levels=3)
     srv = GNNServer(*server_args, buckets=buckets)
     plain = GNNServer(*server_args, buckets=buckets, cache_entries=0)
+    calls = {"n": 0}
+    compose = engine.compose_entries
+
+    def counting(*a, **kw):
+        calls["n"] += 1
+        return compose(*a, **kw)
+
+    monkeypatch.setattr(engine, "compose_entries", counting)
     for members in sets:  # one warm batch of each size
-        _, compose = _serve(srv, members)
-        assert compose.attrs == {"composed_hit": 0,
-                                 "programs": 1 + len(members)}
+        _, span = _serve(srv, members)
+        assert span.attrs == {}
     compiles = srv.n_compose_compiles
     assert compiles > 0
     residents = list(srv.cache._entries.values())
-    memoized = list(srv._composed.values())
-    held_l1, held_l2 = _snapshot(residents), _snapshot(memoized)
+    held = _snapshot(residents)
+    steps = calls["n"]
     for round_ in range(3):
         for members in sets:
             order = [members[i] for i in rng.permutation(len(members))]
-            logits, compose = _serve(srv, order)
-            if not compose.attrs["composed_hit"]:
-                assert compose.attrs["programs"] == 1 + len(order)
+            logits, _ = _serve(srv, order)
             want, _ = _serve(plain, order)  # the whole-batch build
-            for got, ref in zip(logits, want):
+            again, _ = _serve(srv, order)   # the same order composes anew
+            for got, ref, rep in zip(logits, want, again):
                 np.testing.assert_array_equal(got, ref)
-    assert len(srv._composed) > len(memoized)  # new orders were composed
+                np.testing.assert_array_equal(rep, got)
+            steps += 2
+            assert calls["n"] == steps  # every cached step composes
     assert srv.n_compose_compiles == compiles
-    _unchanged(residents, held_l1)
-    _unchanged(memoized, held_l2)
-    # the same order again is a memo hit: no program dispatched
-    _, compose = _serve(srv, sets[0])
-    assert compose.attrs == {"composed_hit": 1, "programs": 0}
+    _unchanged(residents, held)
+
+
+def test_serving_keeps_no_composed_batch(server_args):
+    """Once the tile cache is warm, serving new orders of its members
+    leaves nothing on the device: the live arrays do not grow with the
+    number of orders served."""
+    rng = np.random.default_rng(11)
+    members = [_request(rng, 100) for _ in range(4)]
+    buckets = make_buckets(node_budget=512, edge_budget=4096, levels=3)
+    srv = GNNServer(*server_args, buckets=buckets)
+    orders = [[members[i] for i in p]
+              for p in itertools.permutations(range(len(members)))]
+    for order in orders[:2]:  # caches the members and compiles
+        _serve(srv, order)
+    gc.collect()
+    live = len(jax.live_arrays())
+    for order in orders[2:12]:
+        _serve(srv, order)
+    gc.collect()
+    assert len(jax.live_arrays()) <= live
 
 
 def test_composed_entry_lives_on_its_replicas_device():
@@ -177,11 +205,20 @@ for _ in range(8):
         edges=rng.integers(0, n, size=(2, n)).astype(np.int32),
         features=rng.standard_normal((n, {D_IN})).astype(np.float32),
         n_nodes=n))
+composed = []
+run = srv._execute_plan
+
+def spy(plan, rep=0):
+    logits, entry = run(plan, rep)
+    composed.append((rep, entry))
+    return logits, entry
+
+srv._execute_plan = spy
 srv.drain()
 second = jax.devices()[1]
 on_second = 0
-for key, entry in srv._composed.items():
-    dev = srv._replica_dev[key[-1]]
+for rep, entry in composed:
+    dev = srv._replica_dev[rep]
     for f in ("adj", "inv_deg", "a_packed", "occupancy", "compact_idx",
               "compact_counts", "sgt_idx", "sgt_counts"):
         assert getattr(entry, f).devices() == {{dev}}, (f, dev)

@@ -1,7 +1,7 @@
 """repro.tune: table semantics, the dispatch fallback chain, sweep harness.
 
-Every test pins its table explicitly (``use_table`` / ``tuning_table=``)
-so outcomes never depend on whether the committed artifact is present.
+Every test pins its table explicitly (``use_table``) so outcomes never
+depend on whether the committed artifact is present.
 """
 import json
 import warnings
@@ -184,130 +184,6 @@ def test_sweep_smoke_grid_rejects_and_measures():
     assert [r["op"] for r in res.records] == ["bitserial_gemm"] * 2
     assert all(r["phase"] == "sweep" for r in res.records)
     assert sum(r.get("best", False) for r in res.records) == 1
-
-
-# ------------------------------------------------------- serve consumption
-
-def test_gnnserver_resolves_bucket_policies_from_table():
-    from repro.graph import datasets, partition
-    from repro.models import gnn
-    from repro.serve import GNNServer, SubgraphRequest
-    from repro.serve.queue import buckets_for, requests_from_partitions
-    import jax
-
-    data = datasets.load("ogbn-arxiv", scale=0.004, seed=0)
-    parts = partition.partition(data.csr, 4)
-    cfg = gnn.GNNConfig.paper_gcn(data.features.shape[1], data.n_classes)
-    qparams = gnn.quantize_params(
-        gnn.init_params(jax.random.PRNGKey(0), cfg), cfg)
-    reqs = requests_from_partitions(data, parts)
-    buckets = buckets_for(reqs, levels=2)
-    table = TuningTable([
-        TableEntry(op="serve_forward", bits=8, sparsity_band=0.8,
-                   shape_bucket=(b.n_pad, b.n_pad, cfg.in_dim),
-                   policy=ExecutionPolicy(jump="compact"), backend="pallas")
-        for b in buckets])
-
-    def run(server):
-        ids = [server.submit(SubgraphRequest(edges=r.edges,
-                                             features=r.features,
-                                             n_nodes=r.n_nodes))
-               for r in reqs]
-        out = server.drain(return_logits=True)
-        return [out[i][1] for i in ids]
-
-    tuned = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                      tuning_table=table)
-    plain = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                      tuning_table=None)
-    lg_tuned, lg_plain = run(tuned), run(plain)
-    # the bucket policies really came from the table...
-    pols = tuned.tuned_policies()
-    assert pols and all(p is not None and p["jump"] == "compact"
-                        for p in pols.values())
-    assert plain.tuned_policies() == {}
-    # ...the jit cache stayed bounded, and tuning never changed answers
-    assert 0 < tuned.n_compiles <= len(buckets)
-    for got, want in zip(lg_tuned, lg_plain):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_gnnserver_tuned_sgt_policy_bit_identical():
-    """A tuning table that picked ``jump="sgt"`` per bucket serves with
-    the translated kernels: logits bit-identical to the untuned server,
-    every resolved bucket policy is SGT, and the jit cache stays bounded
-    by the bucket ladder (the acceptance contract for tuned-SGT serving)."""
-    from repro.graph import datasets, partition
-    from repro.models import gnn
-    from repro.serve import GNNServer, SubgraphRequest
-    from repro.serve.queue import buckets_for, requests_from_partitions
-    import jax
-
-    data = datasets.load("ogbn-arxiv", scale=0.004, seed=0)
-    parts = partition.partition(data.csr, 4)
-    cfg = gnn.GNNConfig.paper_gcn(data.features.shape[1], data.n_classes)
-    qparams = gnn.quantize_params(
-        gnn.init_params(jax.random.PRNGKey(0), cfg), cfg)
-    reqs = requests_from_partitions(data, parts)
-    buckets = buckets_for(reqs, levels=2)
-    table = TuningTable([
-        TableEntry(op="serve_forward", bits=8, sparsity_band=0.8,
-                   shape_bucket=(b.n_pad, b.n_pad, cfg.in_dim),
-                   policy=ExecutionPolicy(jump="sgt"), backend="pallas")
-        for b in buckets])
-
-    def run(server):
-        ids = [server.submit(SubgraphRequest(edges=r.edges,
-                                             features=r.features,
-                                             n_nodes=r.n_nodes))
-               for r in reqs]
-        out = server.drain(return_logits=True)
-        return [out[i][1] for i in ids]
-
-    tuned = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                      tuning_table=table)
-    plain = GNNServer(qparams, cfg, backend="pallas", buckets=buckets,
-                      tuning_table=None)
-    lg_tuned, lg_plain = run(tuned), run(plain)
-    pols = tuned.tuned_policies()
-    assert pols and all(p is not None and p["jump"] == "sgt"
-                        for p in pols.values())
-    assert 0 < tuned.n_compiles <= len(buckets)
-    for got, want in zip(lg_tuned, lg_plain):
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
-def test_gnnserver_survives_missing_table_file(tmp_path):
-    from repro.models import gnn
-    from repro.serve import GNNServer
-    import jax
-
-    cfg = gnn.GNNConfig.paper_gcn(8, 4)
-    qparams = gnn.quantize_params(
-        gnn.init_params(jax.random.PRNGKey(0), cfg), cfg)
-    with pytest.warns(RuntimeWarning, match="not found"):
-        srv = GNNServer(qparams, cfg,
-                        tuning_table=tmp_path / "missing.json")
-    assert srv._table is None  # degraded to untuned, construction survived
-
-
-def test_gnnserver_rejects_grid_changing_table_entry():
-    """A tuned construction policy must not invalidate the bucket ladder:
-    an entry whose tile footprint doesn't divide the batcher tile is
-    ignored (ambient grid holds), not applied."""
-    from repro.models import gnn
-    from repro.serve import GNNServer
-    import jax
-
-    cfg = gnn.GNNConfig.paper_gcn(8, 4)
-    qparams = gnn.quantize_params(
-        gnn.init_params(jax.random.PRNGKey(0), cfg), cfg)
-    table = TuningTable([
-        TableEntry(op="serve_forward", bits=8, sparsity_band=0.0,
-                   shape_bucket=(128, 128, 8),
-                   policy=ExecutionPolicy(block_w=3))])  # lcm(8,96)=96 ∤ 128
-    srv = GNNServer(qparams, cfg, tuning_table=table)  # must not raise
-    assert srv._align == 128  # the default grid held
 
 
 # ------------------------------------------------------ policy validation
