@@ -31,6 +31,7 @@ __all__ = [
     "bitserial_matmul",
     "bitserial_matmul_planes",
     "bitserial_matmul_packed",
+    "np_pack_planes",
 ]
 
 WORD = 32
@@ -191,3 +192,35 @@ def np_pack_words(bits: np.ndarray) -> np.ndarray:
     shaped = bits.reshape(bits.shape[:-1] + (-1, WORD)).astype(np.uint32)
     weights = (np.uint32(1) << np.arange(WORD, dtype=np.uint32))
     return (shaped * weights).sum(-1, dtype=np.uint32)
+
+
+# Masks and shifts of an 8x8 bit-matrix transpose on a uint64 whose byte r is
+# row r (Hacker's Delight, 7-3): bit c of byte r swaps with bit r of byte c.
+_T8 = tuple((np.uint64(s), np.uint64(m)) for s, m in (
+    (7, 0x00AA00AA00AA00AA), (14, 0x0000CCCC0000CCCC), (28, 0x00000000F0F0F0F0)))
+
+
+def np_pack_planes(q: np.ndarray, nbits: int) -> np.ndarray:
+    """Host-side bit-plane pack: (N, D) unsigned q -> (nbits, N, ceil(D/32)) uint32.
+
+    Plane i holds bit i of every value, 32 features a word, little-endian
+    (feature 32*w + j is bit j of word w): the words ``np_pack_words`` makes
+    of ``(q >> i) & 1``, with D zero-padded to a word. Works a byte of q at
+    a time (bits 8*b .. 8*b+7 come from byte b), at one byte per bit: each
+    uint64 of 8 features' bytes is 8x8 bit-transposed into 8 plane bytes.
+    """
+    n, d = q.shape
+    dp = -(-d // WORD) * WORD
+    out = np.empty((nbits, n, dp // 8), np.uint8)
+    for b in range(0, nbits, 8):
+        qb = np.zeros((n, dp), np.uint8)
+        qb[:, :d] = q >> b  # the low byte: bits b .. b+7
+        x = qb.view("<u8")  # byte j: feature 8*c + j
+        for s, m in _T8:
+            t = (x ^ (x >> s)) & m
+            x = x ^ t ^ (t << s)
+        # now byte i of x[:, c] packs bit b+i of features 8*c .. 8*c+7
+        planes = x.astype("<u8", copy=False).view(np.uint8).reshape(n, -1, 8)
+        k = min(8, nbits - b)
+        out[b:b + k] = planes.transpose(2, 0, 1)[:k]
+    return out.view("<u4")

@@ -11,6 +11,12 @@ Three host->device transfer strategies, mirroring Fig. 9b:
 On TPU the PCIe economics become host->HBM infeed; the trade is identical:
 one large contiguous DMA beats several small ones, and shipping the sparse
 form trades cheap on-device compute for scarce link bandwidth.
+
+The host side of III quantizes the features to the narrowest unsigned type
+that holds ``nbits`` (``uint8`` up to 8 bits), packs their bit planes at one
+byte per bit (``bitops.np_pack_planes``), and writes header, edges and
+planes into one preallocated ``uint32`` buffer. The layout is that of the
+paper's bit-plane stack: plane i, 32 features a word, little-endian.
 """
 from __future__ import annotations
 
@@ -20,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.bitops import np_pack_words
+from repro.core.bitops import np_pack_planes
 from repro.graph.batching import SubgraphBatch
 from repro.graph.sparse import sparse_to_dense
 from repro.perf import spans
@@ -36,21 +42,33 @@ def _quantize_feats(features: np.ndarray, nbits: int):
     fmin, fmax = float(features.min()), float(features.max())
     scale = max((fmax - fmin) / (1 << nbits), 1e-8)
     q = np.clip(np.floor((features - fmin) / scale), 0, (1 << nbits) - 1)
-    return q.astype(np.uint32), scale, fmin
+    # the narrowest unsigned type that holds nbits: uint8 up to 8 bits
+    return q.astype(np.min_scalar_type((1 << nbits) - 1)), scale, fmin
 
 
-def _pack_body(batch: SubgraphBatch, nbits: int, e_cap: int):
-    """Shared compound-layout core: quantize + bit-plane-pack + header."""
-    q, scale, zero = _quantize_feats(batch.features, nbits)
-    n, d = q.shape
-    planes = np.stack([(q >> i) & 1 for i in range(nbits)])  # (nbits, N, D)
-    packed = np_pack_words(planes)  # (nbits, N, ceil(D/32))
-    wpf = packed.shape[-1]
-    header = np.array([batch.n_nodes, batch.n_valid, batch.n_edges, d, nbits,
-                       e_cap, wpf, 0], dtype=np.uint32)
-    meta = {"scale": scale, "zero": zero, "n": n, "d": d, "nbits": nbits,
-            "e_cap": e_cap, "wpf": wpf}
-    return header, packed, meta
+def _pack(batch: SubgraphBatch, nbits: int, edges: np.ndarray | None):
+    """Shared compound-layout core: header | edges (if any) | packed planes.
+
+    Quantizes, packs the bit planes at one byte per bit
+    (``np_pack_planes``) and writes every part into one preallocated
+    buffer.
+    """
+    with spans.span("pack"):
+        q, scale, zero = _quantize_feats(batch.features, nbits)
+        n, d = q.shape
+        planes = np_pack_planes(q, nbits)  # (nbits, N, ceil(D/32))
+        wpf = planes.shape[-1]
+        e_cap = 0 if edges is None else edges.shape[1]
+        off = _HDR + 2 * e_cap
+        buf = np.empty(off + planes.size, np.uint32)
+        buf[:_HDR] = (batch.n_nodes, batch.n_valid, batch.n_edges, d, nbits,
+                      e_cap, wpf, 0)
+        if e_cap:
+            buf[_HDR:off] = edges.astype(np.int32).view(np.uint32).ravel()
+        buf[off:] = planes.ravel()
+        meta = {"scale": scale, "zero": zero, "n": n, "d": d, "nbits": nbits,
+                "e_cap": e_cap, "wpf": wpf}
+    return buf, meta
 
 
 def pack_compound(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dict]:
@@ -61,15 +79,7 @@ def pack_compound(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dic
     the transfer cost scales with nbits (the paper's bit-level saving
     extends to the link, not just HBM).
     """
-    with spans.span("pack"):
-        header, packed, meta = _pack_body(batch, nbits,
-                                          batch.edges.shape[1])
-        buf = np.concatenate([
-            header,
-            batch.edges.astype(np.int32).view(np.uint32).ravel(),
-            packed.ravel(),
-        ])
-    return buf, meta
+    return _pack(batch, nbits, batch.edges)
 
 
 def pack_feats(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dict]:
@@ -81,10 +91,7 @@ def pack_feats(batch: SubgraphBatch, nbits: int = 8) -> tuple[np.ndarray, dict]:
     Same header/bit-plane layout as :func:`pack_compound`, minus the edges
     (header e_cap = 0).
     """
-    with spans.span("pack"):
-        header, packed, meta = _pack_body(batch, nbits, e_cap=0)
-        buf = np.concatenate([header, packed.ravel()])
-    return buf, meta
+    return _pack(batch, nbits, None)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "nbits", "wpf"))

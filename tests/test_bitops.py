@@ -83,3 +83,15 @@ def test_np_pack_words_matches_jax():
     np_packed = bitops.np_pack_words(bits)
     jx_packed = bitops.pack_along_axis(jnp.asarray(bits), axis=-1)
     np.testing.assert_array_equal(np_packed, np.asarray(jx_packed))
+
+
+@pytest.mark.parametrize("nbits", [3, 8, 11])
+def test_np_pack_planes_matches_pack_a(nbits):
+    """The host plane packer equals the JAX column-wise packer (D=77)."""
+    rng = np.random.default_rng(nbits)
+    q = rng.integers(0, 1 << nbits, (9, 77))
+    dtype = np.uint8 if nbits <= 8 else np.uint16
+    got = bitops.np_pack_planes(q.astype(dtype), nbits)
+    want = bitops.pack_a(jnp.asarray(q, jnp.int32), nbits)
+    assert got.shape == (nbits, 9, 3)
+    np.testing.assert_array_equal(got, np.asarray(want))

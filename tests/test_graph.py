@@ -1,8 +1,10 @@
 """Graph substrate: partitioner invariants, CSR, batching, packed transfer."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import bitops
 from repro.graph import batching, datasets, packing, partition
 from repro.graph.sparse import CSR, edges_to_csr, sparse_to_dense
 
@@ -98,3 +100,82 @@ def test_packed_transfer_byte_accounting():
     b = batching.make_batches(data, parts, batch_size=2, tile=64)[0]
     nb = packing.compound_nbytes(b, nbits=8)
     assert nb["III_packed"] < nb["II_sparse"] < nb["I_dense"]
+
+
+def _synthetic_batch(d, kind, seed=0, n_nodes=64, n_valid=50, e_cap=96):
+    """A padded batch: rows past ``n_valid`` zero, edges -1 padded."""
+    rng = np.random.default_rng(seed)
+    feats = np.zeros((n_nodes, d), np.float32)
+    if kind == "constant":  # fmin == fmax: the 1e-8 scale floor
+        n_valid = n_nodes
+        feats[:] = 0.7
+    else:
+        feats[:n_valid] = rng.standard_normal((n_valid, d)) * 3 + 1
+    n_edges = 0 if kind == "no_edges" else e_cap - 9
+    edges = np.full((2, 0 if kind == "no_edges" else e_cap), -1, np.int32)
+    edges[:, :n_edges] = rng.integers(0, n_valid, (2, n_edges))
+    return batching.SubgraphBatch(
+        edges=edges, n_nodes=n_nodes, n_valid=n_valid, features=feats,
+        labels=np.full(n_nodes, -1, np.int32),
+        train_mask=np.zeros(n_nodes, bool),
+        node_ids=np.full(n_nodes, -1, np.int64), n_edges=n_edges)
+
+
+def _reference_pack(b, nbits, with_edges):
+    """The compound layout as a uint32 plane stack and np_pack_words."""
+    f = b.features
+    fmin, fmax = float(f.min()), float(f.max())
+    scale = max((fmax - fmin) / (1 << nbits), 1e-8)
+    q = np.clip(np.floor((f - fmin) / scale), 0, (1 << nbits) - 1)
+    q = q.astype(np.uint32)
+    planes = np.stack([(q >> i) & 1 for i in range(nbits)])
+    packed = bitops.np_pack_words(planes)
+    e_cap = b.edges.shape[1] if with_edges else 0
+    n, d = q.shape
+    header = np.array([b.n_nodes, b.n_valid, b.n_edges, d, nbits, e_cap,
+                       packed.shape[-1], 0], dtype=np.uint32)
+    parts = [header, packed.ravel()]
+    if with_edges:
+        parts.insert(1, b.edges.astype(np.int32).view(np.uint32).ravel())
+    meta = {"scale": scale, "zero": fmin, "n": n, "d": d, "nbits": nbits,
+            "e_cap": e_cap, "wpf": packed.shape[-1]}
+    return np.concatenate(parts), meta
+
+
+_PACK_CASES = (
+    [(nbits, d, "random") for nbits in (*range(1, 9), 9, 16)
+     for d in (128, 100, 32, 5)]
+    + [(8, 128, "constant"), (3, 100, "constant"), (8, 128, "no_edges"),
+       (9, 5, "no_edges")])
+
+
+@pytest.mark.parametrize("nbits,d,kind", _PACK_CASES)
+def test_pack_matches_plane_stack_reference(nbits, d, kind):
+    """pack_compound / pack_feats are byte-identical to the plane stack."""
+    b = _synthetic_batch(d, kind, seed=nbits * 1000 + d)
+    for fn, with_edges in ((packing.pack_compound, True),
+                           (packing.pack_feats, False)):
+        buf, meta = fn(b, nbits=nbits)
+        want, want_meta = _reference_pack(b, nbits, with_edges)
+        assert buf.dtype == np.uint32 and buf.shape == want.shape
+        assert buf.tobytes() == want.tobytes()
+        assert meta == want_meta
+    nb = packing.compound_nbytes(b, nbits=nbits)
+    assert nb["III_packed"] == packing.pack_compound(b, nbits)[0].nbytes
+    assert nb["III_feats"] == packing.pack_feats(b, nbits)[0].nbytes
+
+
+def test_pack_compound_roundtrip_matches_dense():
+    """A 9-bit, D=100 compound buffer unpacks to strategy I's tensors."""
+    b = _synthetic_batch(100, "random", seed=7)
+    adj_d, feats_d = packing.transfer_dense(b)
+    buf, meta = packing.pack_compound(b, nbits=9)
+    adj_p, packed = packing.unpack_compound(
+        jnp.asarray(buf), n=meta["n"], d=meta["d"], nbits=meta["nbits"],
+        e_cap=meta["e_cap"], wpf=meta["wpf"])
+    np.testing.assert_array_equal(np.asarray(adj_p), np.asarray(adj_d))
+    xq = bitops.bit_compose(bitops.unpack_along_axis(packed, axis=2,
+                                                     size=meta["d"]))
+    x = np.asarray(xq, np.float32) * meta["scale"] + meta["zero"]
+    err = np.abs(x - np.asarray(feats_d))
+    assert err.max() <= meta["scale"] * 1.001
